@@ -17,6 +17,8 @@ def _csv(name, us_per_call, derived):
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     t_all = time.time()
     from benchmarks import q1_memory, q2_throughput, q3_ablation, q4_staleness
 

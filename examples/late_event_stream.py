@@ -10,6 +10,7 @@
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.cleanup import PredictiveCleanup
 from repro.core.staleness import (
     deltaev_times, deltat_times, executions_for_bound, max_staleness_of,
@@ -63,6 +64,7 @@ def prestage_demo():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     cleanup_demo()
     trigger_demo()
     prestage_demo()

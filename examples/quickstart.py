@@ -9,6 +9,7 @@ memory stays bounded because past-window state lives in the p-bucket,
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import AionConfig
 from repro.configs.workloads import AVERAGE
 from repro.core import (
@@ -19,6 +20,7 @@ from repro.data.generators import make_generator
 
 
 def main():
+    enable_compile_cache()
     gen = make_generator(AVERAGE, seed=0)
     aion = AionConfig(block_size=512, max_staleness=0.05)
     engine = StreamEngine(

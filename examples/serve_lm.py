@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.cleanup import PredictiveCleanup
 from repro.serve.kvcache import TieredKVCache
 from repro.serve.scheduler import ContinuousBatcher, Request
@@ -21,6 +22,7 @@ HKV, D, PAGE = 4, 64, 16
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     cache = TieredKVCache(
         num_device_pages=24, page_size=PAGE, num_kv_heads=HKV, head_dim=D,

@@ -15,6 +15,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, reduced
 from repro.data.generators import token_batches
 from repro.data.pipeline import PrefetchPipeline
@@ -25,6 +26,7 @@ from repro.train.train_step import init_train_state
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)

@@ -90,6 +90,22 @@ from repro.obs import profiler_annotation
 _SPLITK_MAX_CHUNKS = 8
 
 
+def _splitk_groups(num_rows: int, chunk: int) -> List[Tuple[int, int]]:
+    """``(offset, rows)`` launch groups for ``num_rows`` rows padded to a
+    multiple of ``chunk``: the chunk count decomposes greedily into groups
+    of {8, 4, 2, 1} chunks, so every group has one of at most four sizes
+    ``{1,2,4,8} * chunk`` (only the last group holds padding)."""
+    groups = []
+    off = 0
+    remaining = -(-num_rows // chunk)
+    while remaining:
+        g = min(_SPLITK_MAX_CHUNKS, 1 << (remaining.bit_length() - 1))
+        groups.append((off, g * chunk))
+        off += g * chunk
+        remaining -= g
+    return groups
+
+
 @dataclass
 class BatchWorkItem:
     """One due window execution (live expiry or late re-execution)."""
@@ -234,28 +250,35 @@ class BatchExecutor:
 
         with span:
             t0 = _time.time()
+            # the round's lease on its windows: destages queued before it
+            # yield instead of undoing its demand fills mid-round
+            for it in items:
+                it.state.folding += 1
+            try:
+                # 1. snapshot every window atomically (membership is
+                #    fixed from here on: each block folds exactly once,
+                #    whatever tier it moves to while the batch assembles)
+                plans = [(it, sum(snapshot_block_partition(it.state), []))
+                         for it in items]
 
-            # 1. snapshot every window atomically (membership is fixed
-            #    from here on: each block folds exactly once, whatever
-            #    tier it moves to while the batch assembles)
-            plans = [(it, sum(snapshot_block_partition(it.state), []))
-                     for it in items]
+                mesh = self._slot_mesh()
+                num_devices = mesh.size if mesh is not None else 1
 
-            mesh = self._slot_mesh()
-            num_devices = mesh.size if mesh is not None else 1
-
-            with profiler_annotation(
-                    f"aion.fold_round[{len(items)}]",
-                    enabled=getattr(eng.aion, "profiler_annotations",
-                                    False)):
-                if eng.pool is not None:
-                    results, slot_of, num_slots, dev_dt, gather_dt, \
-                        ran_sharded = self._fold_pooled(plans, mesh,
-                                                        num_devices)
-                else:
-                    results, slot_of, num_slots, dev_dt, gather_dt, \
-                        ran_sharded = self._fold_stacked(plans, mesh,
-                                                         num_devices)
+                with profiler_annotation(
+                        f"aion.fold_round[{len(items)}]",
+                        enabled=getattr(eng.aion, "profiler_annotations",
+                                        False)):
+                    if eng.pool is not None:
+                        results, slot_of, num_slots, dev_dt, gather_dt, \
+                            ran_sharded = self._fold_pooled(plans, mesh,
+                                                            num_devices)
+                    else:
+                        results, slot_of, num_slots, dev_dt, gather_dt, \
+                            ran_sharded = self._fold_stacked(plans, mesh,
+                                                             num_devices)
+            finally:
+                for it in items:
+                    it.state.folding -= 1
 
             # per-window bookkeeping, identical to execute_window
             out: Dict[WindowId, Any] = {}
@@ -310,44 +333,39 @@ class BatchExecutor:
             return 0
         return chunk
 
-    def _plan_table_groups(self, rows, num_devices: int, slots_per: int):
+    def _plan_table_groups(self, rows, num_devices: int, slots_per: int,
+                           chunk: Optional[int] = None):
         """Launch groups ``[(table, fills, slots, splitk)]`` for pooled
         (block, window_slot, pool_slot) rows.
 
+        ``chunk`` is the round's split-K chunk (``_splitk_chunk`` of the
+        round's whole row count, so the resident/staged split of a round
+        never changes its launch shapes); None decides from ``rows``.
         Split-K disabled (or sharded — the sharded layout keeps the
         ownership packing and chunks per shard inside the kernel): one
         legacy pow2-padded group. Single-device split-K: rows pad to a
         chunk multiple (pool slot 0, fill 0 — invalid everywhere,
-        including the ±inf min/max identities) and the chunk count
-        decomposes greedily into groups of {8, 4, 2, 1} chunks, so every
-        launch shape is one of at most four ``{1,2,4,8} * chunk_rows``
-        shapes regardless of batch size — zero recompiles as rounds vary,
-        where the stripe path re-jits per pow2 bucket. Cross-group
-        partials merge via ``op.merge_acc`` in the shared tail."""
-        chunk = self._splitk_chunk(len(rows), num_devices)
+        including the ±inf min/max identities) and decompose into the
+        ``_splitk_groups`` repertoire of at most four shapes regardless
+        of batch size — zero recompiles as rounds vary, where the stripe
+        path re-jits per pow2 bucket. Cross-group partials merge via
+        ``op.merge_acc`` in the shared tail."""
+        if chunk is None:
+            chunk = self._splitk_chunk(len(rows), num_devices)
         if chunk == 0 or num_devices > 1:
             tbl, fills, slots = self._pack_table(rows, num_devices,
                                                  slots_per)
             return [(tbl, fills, slots, chunk)]
-        table = [ps for _, _, ps in rows]
-        fills = [blk.fill for blk, _, _ in rows]
-        slots = [ws for _, ws, _ in rows]
-        for _ in range((-len(rows)) % chunk):
-            table.append(0)
-            fills.append(0)
-            slots.append(0)
         groups = []
-        off = 0
-        remaining = len(table) // chunk
-        while remaining:
-            g = min(_SPLITK_MAX_CHUNKS, 1 << (remaining.bit_length() - 1))
-            n = g * chunk
-            groups.append((jnp.asarray(table[off:off + n], jnp.int32),
-                           jnp.asarray(fills[off:off + n], jnp.int32),
-                           jnp.asarray(slots[off:off + n], jnp.int32),
-                           chunk))
-            off += n
-            remaining -= g
+        for off, n in _splitk_groups(len(rows), chunk):
+            part = rows[off:off + n]
+            pad = [0] * (n - len(part))
+            groups.append((
+                jnp.asarray([ps for _, _, ps in part] + pad, jnp.int32),
+                jnp.asarray([blk.fill for blk, _, _ in part] + pad,
+                            jnp.int32),
+                jnp.asarray([ws for _, ws, _ in part] + pad, jnp.int32),
+                chunk))
         return groups
 
     def _fold_table_groups(self, groups, arena_data, num_slots, use_mesh,
@@ -367,7 +385,7 @@ class BatchExecutor:
         return _time.time() - d0
 
     def _stack_rows(self, rows, num_devices: int, slots_per: int,
-                    balance: bool = False):
+                    balance: bool = False, pad_to: int = 0):
         """Stacked (data, fills, slots) tensors from (arrays, fill,
         window_slot) rows.
 
@@ -384,7 +402,8 @@ class BatchExecutor:
         values only: no batch fold is time-dependent within a window,
         and stacking timestamps would force a D2H pull of every hot
         device-resident row (f64 on host, f32 on device — see the
-        fold_batch contract).
+        fold_batch contract). ``pad_to`` replaces the pow2 padding with a
+        fixed row count (one split-K group of the unsharded fallback).
         """
         eng = self.engine
         cap = eng.aion.block_size
@@ -392,6 +411,8 @@ class BatchExecutor:
         per_shard, rows_per_shard = pack_rows_shard_major(
             [slot for _, _, slot in rows], num_devices, slots_per,
             balance=balance)
+        if pad_to:
+            rows_per_shard = pad_to
         pad_arrs = {
             "keys": np.zeros((cap,), np.int32),
             "values": np.zeros((cap, w), np.float32),
@@ -538,6 +559,8 @@ class BatchExecutor:
             for blk in blks:
                 if blk.fill:
                     blocks.append((blk, i))
+        # one split-K decision per round, from its whole row count
+        chunk = self._splitk_chunk(len(blocks), num_devices)
 
         def well_placed(ps, i):
             return num_devices <= 1 or \
@@ -626,7 +649,7 @@ class BatchExecutor:
                             fallback.append((blk, slot_of[i]))
                     if pooled:
                         groups = self._plan_table_groups(
-                            pooled, num_devices, slots_per)
+                            pooled, num_devices, slots_per, chunk)
                         arena_data = {"keys": k_arena, "values": v_arena}
                         gather_dt += _time.time() - g0
                         dev_dt += self._fold_table_groups(
@@ -638,7 +661,7 @@ class BatchExecutor:
                         gather_dt += _time.time() - g0
             return self._fold_pooled_tail(
                 plans, accs, fallback, slot_of, num_slots, dev_dt,
-                gather_dt, ran_sharded)
+                gather_dt, ran_sharded, chunk)
 
         # the whole batch runs under ONE pool pin: any fill that lands
         # while a fold may be executing takes the functional (copy) path,
@@ -682,7 +705,7 @@ class BatchExecutor:
             if pooled:
                 g0 = _time.time()
                 groups = self._plan_table_groups(pooled, num_devices,
-                                                 slots_per)
+                                                 slots_per, chunk)
                 gather_dt += _time.time() - g0
                 dev_dt += self._fold_table_groups(groups, arena_data,
                                                   num_slots, use_mesh,
@@ -712,7 +735,7 @@ class BatchExecutor:
                 if staged:
                     g0 = _time.time()
                     groups = self._plan_table_groups(
-                        staged, num_devices, slots_per)
+                        staged, num_devices, slots_per, chunk)
                     arena2 = {"keys": k2, "values": v2}
                     gather_dt += _time.time() - g0
                     dev_dt += self._fold_table_groups(
@@ -722,13 +745,16 @@ class BatchExecutor:
 
         return self._fold_pooled_tail(plans, accs, fallback, slot_of,
                                       num_slots, dev_dt, gather_dt,
-                                      ran_sharded)
+                                      ran_sharded, chunk)
 
     def _fold_pooled_tail(self, plans, accs, fallback, slot_of, num_slots,
-                          dev_dt, gather_dt, ran_sharded):
+                          dev_dt, gather_dt, ran_sharded, chunk=0):
         """Shared tail of both pooled pin strategies: fold the fallback
         rows through the stacked gather, then merge the partial
-        accumulators into per-slot results."""
+        accumulators into per-slot results. Under a split-K round
+        (``chunk > 0``) the fallback rows stack in the same fixed
+        ``_splitk_groups`` sizes as the table groups, so a fallback count
+        that varies with tier timing never compiles a new shape."""
         eng = self.engine
         op = eng.operator
         if fallback:
@@ -742,13 +768,20 @@ class BatchExecutor:
                 rows.append((arrs, blk.fill, wslot))
             if rows:
                 # unsharded fold (any global slot id is valid on one
-                # device), rows pow2-padded by the shared stacker
-                data, fills, slots = self._stack_rows(rows, 1, num_slots)
-                gather_dt += _time.time() - g0
-                d0 = _time.time()
-                accs.append(op.fold_batch(data, fills, slots, num_slots,
-                                          mesh=None))
-                dev_dt += _time.time() - d0
+                # device), rows pow2-padded by the shared stacker or cut
+                # into the round's split-K group sizes
+                groups = _splitk_groups(len(rows), chunk) if chunk \
+                    else [(0, 0)]
+                for off, n in groups:
+                    part = rows[off:off + n] if n else rows
+                    data, fills, slots = self._stack_rows(
+                        part, 1, num_slots, pad_to=n)
+                    gather_dt += _time.time() - g0
+                    d0 = _time.time()
+                    accs.append(op.fold_batch(data, fills, slots,
+                                              num_slots, mesh=None))
+                    dev_dt += _time.time() - d0
+                    g0 = _time.time()
                 eng.metrics.fallback_rows += len(rows)
             else:
                 gather_dt += _time.time() - g0

@@ -49,6 +49,9 @@ fold: a window's blocks are allocated in the range of the shard that
 ``distributed.sharding.shard_of_window`` assigns the window to, so the
 block table a shard receives only ever references its own arena range
 (the shard_map passes each device its ``[pool_slots/D, ...]`` arena tile).
+Given the slot mesh, the arenas are placed with a ``NamedSharding`` over
+their slot axis: each device holds its own range from the start, and
+neither fills nor folds ever reshard them.
 """
 from __future__ import annotations
 
@@ -60,6 +63,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.obs import MetricsRegistry, StatsMap
 
@@ -101,7 +105,10 @@ class DeviceBlockPool:
     def __init__(self, pool_slots: int, block_capacity: int, width: int,
                  num_shards: int = 1,
                  max_arena_bytes: Optional[int] = None,
-                 registry: Optional[MetricsRegistry] = None):
+                 registry: Optional[MetricsRegistry] = None,
+                 mesh=None):
+        if mesh is not None:
+            num_shards = mesh.size
         num_shards = max(int(num_shards), 1)
         pool_slots = max(int(pool_slots), num_shards)
         # round up to a multiple of the shard count so the arena splits
@@ -131,7 +138,7 @@ class DeviceBlockPool:
         self._deferred = 0                 # live deferred-fill sections
         # slot -> (keys, values) commits buffered while deferred; flushed
         # as ONE batched scatter at the next snapshot/read
-        self._pending: Dict[int, Tuple[jnp.ndarray, jnp.ndarray]] = {}
+        self._pending: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
         self._free: List[deque] = [
             deque(range(d * self.slots_per_shard,
                         (d + 1) * self.slots_per_shard))
@@ -149,9 +156,15 @@ class DeviceBlockPool:
         # ingest-time fills in between donate in place, O(block)).
         self._slot_epoch: List[int] = [0] * pool_slots
         self.seq = 0                       # global epoch counter
-        self.keys = jnp.zeros((pool_slots, block_capacity), jnp.int32)
+        k_dev = v_dev = None
+        if mesh is not None and num_shards > 1:
+            axis = mesh.axis_names[0]
+            k_dev = NamedSharding(mesh, P(axis, None))
+            v_dev = NamedSharding(mesh, P(axis, None, None))
+        self.keys = jnp.zeros((pool_slots, block_capacity), jnp.int32,
+                              device=k_dev)
         self.values = jnp.zeros((pool_slots, block_capacity, width),
-                                jnp.float32)
+                                jnp.float32, device=v_dev)
         registry = registry if registry is not None else MetricsRegistry()
         self.registry = registry
         self.stats = StatsMap(registry, "aion_pool")
@@ -180,7 +193,10 @@ class DeviceBlockPool:
         or the lease exit — flushes them as ONE batched scatter. Under a
         concurrent ``pinned()`` section each per-block commit would be a
         functional O(arena) copy; the batch makes a round of k fills
-        O(arena + k*block). Slot attachment stays immediate (a pending
+        O(arena + k*block). The buffered fills wait on the host and cross
+        to the device as one stacked transfer at the flush, so a round's
+        k fills never sit on the device twice (as k arrays and as their
+        stack). Slot attachment stays immediate (a pending
         slot is resident for placement purposes); reads always flush
         first, so no path can observe a slot without its data."""
         with self._lock:
@@ -207,9 +223,9 @@ class DeviceBlockPool:
         while n < len(slots):
             n <<= 1
         slots = slots + [slots[0]] * (n - len(slots))
-        ks = jnp.stack([self._pending[s][0] for s in slots])
-        vs = jnp.stack([self._pending[s][1] for s in slots])
-        idx = jnp.asarray(slots, jnp.int32)
+        ks = np.stack([self._pending[s][0] for s in slots])
+        vs = np.stack([self._pending[s][1] for s in slots])
+        idx = np.asarray(slots, np.int32)
         scatter = _scatter_jit if self._pins else _scatter_donated_jit
         if self._pins:
             self.stats.inc("copy_writes")
@@ -308,13 +324,19 @@ class DeviceBlockPool:
         ``block.host_data`` here would race a concurrent spill that just
         nulled it (spill keeps the same bytes on storage, so committing
         the caller's snapshot stays correct, exactly like the legacy
-        ``device_put`` path)."""
-        keys = jnp.asarray(np.asarray(host_data["keys"], np.int32))
-        vals = jnp.asarray(np.asarray(host_data["values"], np.float32))
+        ``device_put`` path). Ingest appends to a host block only under
+        ``block.lock`` too, so the copy taken here holds exactly the
+        events the block's fill counts."""
+        # private copies: the host arrays stay mutable (ingest appends to
+        # a host block's tail) and a device transfer may read its source
+        # after this returns, or alias it outright
+        keys = np.array(host_data["keys"], np.int32)
+        vals = np.array(host_data["values"], np.float32)
         with self._lock:
             if self._deferred:
-                # a fold round's fills batch into one scatter at the
-                # next snapshot/read (see ``deferred_fills``)
+                # a fold round's fills batch into one host stack, one
+                # transfer and one scatter at the next snapshot/read
+                # (see ``deferred_fills``)
                 self._pending[slot] = (keys, vals)
                 self.stats.inc("deferred_fills")
             else:

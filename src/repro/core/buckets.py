@@ -297,6 +297,10 @@ class WindowState:
     last_executed_at: float = -np.inf
     events_at_last_exec: int = 0
     result: Optional[object] = None
+    # batched fold rounds reading this window right now (the batch
+    # executor holds it for the whole round): a destage queued before the
+    # round yields to it instead of undoing the round's demand fills
+    folding: int = 0
 
     def m_blocks(self) -> List[Block]:
         return [b for b in self.blocks if b.tier == Tier.DEVICE]
@@ -315,10 +319,15 @@ class WindowState:
         (device vs host) is decided by the policy/staging layer."""
         new_blocks: List[Block] = []
         start = 0
-        # fill the last block if it has room and is host-resident
-        if self.blocks and not self.blocks[-1].full \
-                and self.blocks[-1].tier == Tier.HOST:
-            start += self.blocks[-1].append(batch, start)
+        # fill the last block if it has room and is host-resident — under
+        # its lock: the I/O thread may be staging or spilling that very
+        # block, and a copy taken there must match the fill it commits
+        last = self.blocks[-1] if self.blocks else None
+        if last is not None and not last.full:
+            with last.lock:
+                if last.tier == Tier.HOST and not last.dropped \
+                        and last.host_data is not None:
+                    start += last.append(batch, start)
         while start < len(batch):
             blk = Block.new(self.block_capacity, self.width)
             blk.window_key = (self.window_start, self.window_end)

@@ -258,12 +258,11 @@ class StreamEngine:
             if self.aion.block_pool and self.aion.batched_execution \
                     and operator.supports_batch:
                 from repro.core.block_pool import DeviceBlockPool
-                shards = 1
+                mesh = None
                 if self.aion.slot_sharding:
                     from repro.distributed.sharding import make_slot_mesh
-                    m = make_slot_mesh(self.aion.slot_shard_devices,
-                                       self.aion.slot_shard_axis)
-                    shards = m.size if m is not None else 1
+                    mesh = make_slot_mesh(self.aion.slot_shard_devices,
+                                          self.aion.slot_shard_axis)
                 # the arena may take at most HALF the budget: the legacy
                 # per-block path keeps headroom, and utilization-driven
                 # policies (GlobalMemoryPolicy's moderate/severe
@@ -274,7 +273,7 @@ class StreamEngine:
                 # bytes)
                 pool = DeviceBlockPool(
                     self.aion.pool_slots, self.aion.block_size,
-                    value_width, num_shards=shards,
+                    value_width, mesh=mesh,
                     max_arena_bytes=device_budget_bytes // 2,
                     registry=self.registry)
                 if pool.pool_slots > 0 \

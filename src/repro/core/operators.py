@@ -259,7 +259,6 @@ def _bigram_segment_count(ids, pval, slots, num_slots: int, vocab: int,
     if mesh is None or mesh.size <= 1:
         return flat_count(ids, pval, slots, num_slots)
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import shard_map_compat
     axis = mesh.axis_names[0]
     num_devices = mesh.shape[axis]
     if ids.shape[0] % num_devices or num_slots % num_devices:
@@ -275,9 +274,9 @@ def _bigram_segment_count(ids, pval, slots, num_slots: int, vocab: int,
         local = jnp.where(own, local, 0)
         return flat_count(ids_, pv_ & own[:, None], local, slots_per)
 
-    f = shard_map_compat(shard_fn, mesh,
-                         (P(axis, None), P(axis, None), P(axis)),
-                         P(axis, None))
+    f = jax.shard_map(shard_fn, mesh=mesh,
+                      in_specs=(P(axis, None), P(axis, None), P(axis)),
+                      out_specs=P(axis, None), check_vma=False)
     return f(ids, pval.astype(bool), slots)
 
 

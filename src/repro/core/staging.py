@@ -467,7 +467,7 @@ class IOScheduler:
             "stage_seconds", "destage_seconds",
             "stage_events", "simulated_io_seconds",
             "preemptions", "pool_fills", "pool_fallbacks",
-            "errors",
+            "destages_yielded", "spilled_blocks", "errors",
             # self-healing path: transient store failures retried (and
             # recovered), retry budgets exhausted (the failure then
             # surfaced honestly), speculative readahead shed instead of
@@ -711,16 +711,11 @@ class IOScheduler:
             # request was queued — surrender the slot/reservation and skip
             return fail()
 
-        device_data = None
-        if slot is None:
-            device_data = {
-                k: jax.device_put(v) for k, v in host_data.items()}
-            for v in device_data.values():
-                v.block_until_ready()
         # commit under the block lock: if predictive cleanup dropped the
         # block while the transfer was in flight, the slot/reservation is
         # ours to surrender (the purge only accounts blocks ALREADY on
-        # device)
+        # device). Ingest appends to a host block's tail under the same
+        # lock, so the copy made here and the fill it serves agree.
         with block.lock:
             if block.dropped:
                 return fail()
@@ -738,7 +733,11 @@ class IOScheduler:
                 self.pool.commit(block, slot, host_data)
                 self.stats.inc("pool_fills")
             else:
-                block.device_data = device_data
+                block.device_data = {
+                    k: jax.device_put(np.array(v))
+                    for k, v in host_data.items()}
+                for v in block.device_data.values():
+                    v.block_until_ready()
             block.tier = Tier.DEVICE
         if block.persisted:       # reads from the persistent tier pay I/O;
             self._simulate_io(self._cost_bytes(block))  # ingest is direct
@@ -1002,6 +1001,12 @@ class IOScheduler:
                     # tombstoned it if it ran), the residency is theirs
                     self._unaccount_unspillable(block)
                     continue
+                if self.store.current_fill(block.window_key,
+                                           block.block_id) != block.fill:
+                    # ingest appended to the block after its record was
+                    # written: the host copy is the only complete one
+                    self._requeue_spill([block])
+                    continue
                 nbytes = block.nbytes
                 block.host_data = None
                 block.tier = Tier.STORAGE
@@ -1011,6 +1016,7 @@ class IOScheduler:
                     block.host_accounted = False
                     self._host_bytes = max(self._host_bytes - nbytes, 0)
             total += nbytes
+            self.stats.inc("spilled_blocks")
         self._simulate_io(total)
 
     # ------------------------------------------------------- bulk requests
@@ -1143,7 +1149,12 @@ class IOScheduler:
                         keep_bootstrap: int = 0,
                         parent=None) -> threading.Event:
         """Queue destaging (background, lowest priority). Preemptible: the
-        executor checks for higher-priority work between chunks."""
+        executor checks for higher-priority work between chunks.
+
+        A window that a fold round is reading (``window.folding``) is left
+        alone: the request predates the round, whose demand fills it
+        would otherwise undo mid-round. The round's own post-execute
+        policy decides the window's residency afterwards."""
         span = self._task_span(parent, "destage", window=_wkey(window))
 
         def do():
@@ -1154,6 +1165,9 @@ class IOScheduler:
             while i < len(pending):
                 chunk = pending[i:i + self.chunk_blocks]
                 for blk in chunk:
+                    if window.folding:
+                        self.stats.inc("destages_yielded")
+                        return
                     self.destage_block_sync(blk)
                 i += len(chunk)
                 if self.sequential_io and \

@@ -64,22 +64,6 @@ class ShardingProfile:
     notes: Tuple[str, ...] = ()
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = False):
-    """Version-gated ``shard_map``.
-
-    ``jax.shard_map`` (with its ``check_vma`` kwarg) only exists on newer
-    jax; older releases ship it as ``jax.experimental.shard_map.shard_map``
-    with the equivalent knob spelled ``check_rep``. Model code calls this
-    helper so it runs on both.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
-
-
 # Default mesh axis for the streaming engine's slot-sharded batched fold
 # (``AionConfig.slot_sharding``): window slots partition across a 1-D mesh
 # with NO cross-device reduction — slots are disjoint, so each device owns

@@ -4,10 +4,17 @@
   'pallas'     real Mosaic lowering (TPU)
   'interpret'  Pallas interpreter (CPU validation — this container)
   'ref'        pure-jnp oracle (numerics baseline)
-  'auto'       pallas on TPU, interpret elsewhere
+  'auto'       pallas on TPU; elsewhere interpret (``segment_aggregate``
+               and the attention/scan kernels) or the dense one-hot jnp
+               fold (the batched and block-table folds)
+
+Every segment fold records the backend it resolved to in
+``resolved_backends`` — an on-chip run reads it to prove that no fold
+fell back to the dense or interpreter path.
 """
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Optional
 
@@ -33,11 +40,23 @@ from repro.kernels.segment_aggregate import (
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
-def _resolve(backend: str) -> str:
+def _resolve(backend: str, off_tpu: str = "interpret") -> str:
     if backend != "auto":
         return backend
     platform = jax.devices()[0].platform
-    return "pallas" if platform == "tpu" else "interpret"
+    return "pallas" if platform == "tpu" else off_tpu
+
+
+#: (fold entry point, backend) -> traces that resolved to it. A jitted
+#: fold resolves once per compiled shape, so this counts compiled
+#: variants, not calls.
+resolved_backends: collections.Counter = collections.Counter()
+
+
+def _resolve_fold(entry: str, backend: str, off_tpu: str = "dense") -> str:
+    be = _resolve(backend, off_tpu)
+    resolved_backends[(entry, be)] += 1
+    return be
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "backend",
@@ -49,7 +68,7 @@ def segment_aggregate(values, segment_ids, num_segments: int, valid=None,
     selection reaches the Pallas out_shapes, so sum/count-only callers
     skip the min/max VPU broadcast-reduce on the Mosaic path too."""
     stats = _norm_stats(stats)
-    be = _resolve(backend)
+    be = _resolve_fold("segment_aggregate", backend, off_tpu="interpret")
     if be == "ref":
         out = _ref.ref_segment_aggregate(values, segment_ids, num_segments,
                                          valid)
@@ -108,10 +127,7 @@ def segment_aggregate_batched(values, segment_ids, num_segments: int,
         # the fold identity (zero sum/count, +/-inf extrema) directly
         empty = _empty_batch_identity(ns, num_segments, values.shape[2])
         return {k: v for k, v in empty.items() if k in stats}
-    if backend == "auto":
-        be = "pallas" if jax.devices()[0].platform == "tpu" else "dense"
-    else:
-        be = backend
+    be = _resolve_fold("segment_aggregate_batched", backend)
     if mesh is not None and be != "ref" and mesh.size > 1:
         if splitk > 0:
             return segment_aggregate_batched_splitk_sharded(
@@ -181,10 +197,7 @@ def segment_aggregate_block_table(values_arena, segment_ids, table,
         w_out = num_cols if num_cols is not None else values_arena.shape[2]
         empty = _empty_batch_identity(ns, num_segments, w_out)
         return {k: v for k, v in empty.items() if k in stats}
-    if backend == "auto":
-        be = "pallas" if jax.devices()[0].platform == "tpu" else "dense"
-    else:
-        be = backend
+    be = _resolve_fold("segment_aggregate_block_table", backend)
     if mesh is not None and be != "ref" and mesh.size > 1:
         return segment_aggregate_block_table_sharded(
             values_arena, segment_ids, table, num_segments, valid=valid,
@@ -248,10 +261,7 @@ def segment_aggregate_block_table_splitk(values_arena, segment_ids, table,
         w_out = num_cols if num_cols is not None else values_arena.shape[2]
         empty = _empty_batch_identity(ns, num_segments, w_out)
         return {k: v for k, v in empty.items() if k in stats}
-    if backend == "auto":
-        be = "pallas" if jax.devices()[0].platform == "tpu" else "dense"
-    else:
-        be = backend
+    be = _resolve_fold("segment_aggregate_block_table_splitk", backend)
     if mesh is not None and be != "ref" and mesh.size > 1:
         return segment_aggregate_block_table_sharded(
             values_arena, segment_ids, table, num_segments, valid=valid,

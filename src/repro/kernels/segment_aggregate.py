@@ -6,11 +6,16 @@ key is hostile to the VPU, so the kernel converts the segment reduction
 into **one-hot matmuls on the MXU** — ``onehot(ids)^T @ values`` — which is
 the TPU-native formulation of reduce-by-key (FeatGraph/GE-SpMM style).
 
-Tiling: grid over event tiles of ``block_n`` rows; each step loads a
-[block_n, W] value tile + [block_n] ids into VMEM, builds the [block_n, S]
-one-hot in registers, and accumulates [S, W] / [S] outputs that stay
-resident in VMEM across the whole grid (output BlockSpecs map every step
-to the same block).
+Tiling: grid over event tiles of ``n`` events; each step loads a
+``[W, n]`` value tile (events on the lanes) and a ``[1, n]`` row of
+segment ids (``-1`` = invalid) into VMEM, builds the one-hot ``[n, S]``
+in chunks of at most 512 segments, and accumulates lane-dense ``[W, S]``
+/ ``[1, S]`` outputs that stay resident in VMEM across the whole grid
+(output BlockSpecs map every step to the same block): sum on the MXU at
+``HIGHEST`` precision, count and min/max as 2-D masked reductions. No
+block breaks the (8, 128) tiling, and events-on-lanes is the TPU's own
+layout for a ``[P, cap, W]`` arena whose width is not a lane multiple,
+so the block-table folds read the arena without relaying it out.
 
 The **batched** entry point (``segment_aggregate_batched_pallas``) extends
 this to many concurrent windows in one device pass: event rows carry a
@@ -89,34 +94,59 @@ def norm_stats(stats) -> Tuple[str, ...]:
     return out
 
 
-def _acc_tile(refs, ids, valid, vals, num_segments: int, n: int) -> None:
-    """Accumulate one [n] ids / [n, W] values tile into the stat refs.
+# one-hot tile width along the segment axis: bounds the [n, S] one-hot
+# and its masked min/max temporaries to [n, 512] whatever the segment count
+_SEG_CHUNK = 512
 
-    Shared by the flat-grid kernel and the block-table kernel. Only the
-    requested stats exist in ``refs``; unrequested aggregates cost
-    nothing (the min/max broadcast-reduce temps are never built for
-    sum/count-only folds)."""
-    seg = jax.lax.broadcasted_iota(jnp.int32, (n, num_segments), 1)
-    onehot = (ids[:, None] == seg) & valid[:, None]     # [n, S]
-    if "sum" in refs or "count" in refs:
-        oh_f = onehot.astype(jnp.float32)
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _acc_tile(refs, ids, vals, num_segments: int) -> None:
+    """Accumulate one event tile into the stat refs.
+
+    ``ids`` is a ``[1, n]`` row of segment ids (``-1`` marks an invalid
+    event) and ``vals`` the ``[W, n]`` values with events on the lanes —
+    the orientation of an arena tile in the TPU's layout for it. The
+    accumulators are lane-dense: ``[W, S]`` for sum/min/max, ``[1, S]``
+    for count. Shared by the flat-grid kernel and the block-table
+    kernels. Only the requested stats exist in ``refs``; unrequested
+    aggregates cost nothing."""
+    n = ids.shape[1]
+    w = vals.shape[0]
+    col = ids.T                                           # [n, 1]
     if "sum" in refs:
-        # MXU path: [S, n] @ [n, W]
-        refs["sum"][...] += jax.lax.dot_general(
-            oh_f, jnp.where(valid[:, None], vals, 0.0),
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    if "count" in refs:
-        refs["count"][...] += jnp.sum(oh_f, axis=0)
-    # min/max: masked broadcast-reduce over the tile (VPU path)
-    if "min" in refs:
-        big = jnp.where(onehot[:, :, None], vals[:, None, :], jnp.inf)
-        refs["min"][...] = jnp.minimum(refs["min"][...],
-                                       jnp.min(big, axis=0))
-    if "max" in refs:
-        small = jnp.where(onehot[:, :, None], vals[:, None, :], -jnp.inf)
-        refs["max"][...] = jnp.maximum(refs["max"][...],
-                                       jnp.max(small, axis=0))
+        # an invalid event contributes nothing even if its values are
+        # not finite (0 * inf would poison the matmul)
+        vals_ok = jnp.where(ids >= 0, vals, 0.0)
+    for s0 in range(0, num_segments, _SEG_CHUNK):
+        c = min(_SEG_CHUNK, num_segments - s0)
+        seg = jax.lax.broadcasted_iota(jnp.int32, (n, c), 1) + s0
+        onehot = col == seg                               # [n, c]
+        if "sum" in refs or "count" in refs:
+            oh_f = onehot.astype(jnp.float32)
+        if "sum" in refs:
+            # MXU path: [W, n] @ [n, c]
+            refs["sum"][:, s0:s0 + c] += jnp.dot(
+                vals_ok, oh_f, precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+        if "count" in refs:
+            refs["count"][:, s0:s0 + c] += jnp.sum(oh_f, axis=0,
+                                                   keepdims=True)
+        # min/max: one masked [n, c] reduction per value column (VPU)
+        for j in range(w if ("min" in refs or "max" in refs) else 0):
+            v = vals[j:j + 1, :].T                        # [n, 1]
+            if "min" in refs:
+                lo = jnp.min(jnp.where(onehot, v, jnp.inf), axis=0,
+                             keepdims=True)
+                refs["min"][j:j + 1, s0:s0 + c] = jnp.minimum(
+                    refs["min"][j:j + 1, s0:s0 + c], lo)
+            if "max" in refs:
+                hi = jnp.max(jnp.where(onehot, v, -jnp.inf), axis=0,
+                             keepdims=True)
+                refs["max"][j:j + 1, s0:s0 + c] = jnp.maximum(
+                    refs["max"][j:j + 1, s0:s0 + c], hi)
 
 
 def _init_refs(refs) -> None:
@@ -129,37 +159,67 @@ def _init_refs(refs) -> None:
             ref[...] = jnp.zeros_like(ref)
 
 
-def _kernel(ids_ref, valid_ref, values_ref, *out_refs, num_segments: int,
-            block_n: int, stats: Tuple[str, ...]):
+def _kernel(ids_ref, values_ref, *out_refs, num_segments: int,
+            stats: Tuple[str, ...]):
     refs = dict(zip(stats, out_refs))
-    step = pl.program_id(0)
 
-    @pl.when(step == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         _init_refs(refs)
 
-    _acc_tile(refs, ids_ref[...], valid_ref[...] != 0, values_ref[...],
-              num_segments, block_n)
+    _acc_tile(refs, ids_ref[...], values_ref[...], num_segments)
 
 
-def _stat_outputs(stats: Tuple[str, ...], num_segments: int, w: int):
-    """(out_shapes, out_specs) for a stats selection; every grid step maps
-    to the same (only) block so accumulators stay VMEM-resident (the
-    variadic index_maps absorb grid indices and any scalar-prefetch
-    operands)."""
-    full2 = pl.BlockSpec((num_segments, w), lambda *a: (0, 0))
-    full1 = pl.BlockSpec((num_segments,), lambda *a: (0,))
+def _stat_outputs(stats: Tuple[str, ...], num_segments: int, w: int,
+                  k: Optional[int] = None):
+    """(out_shapes, out_specs) for a stats selection, in the kernel's
+    lane-dense layout (``[W, S]``, count ``[1, S]``). Every grid step maps
+    to the same block so accumulators stay VMEM-resident (the variadic
+    index_maps absorb grid indices and any scalar-prefetch operands).
+
+    ``k`` adds the split-K leading chunk axis ``[k, ...]``: chunk ``c``'s
+    programs all map to block ``c``, so each chunk's partial accumulator
+    stays resident across its inner steps (the grid iterates the row
+    axis fastest) and is re-initialized when the next chunk begins."""
     shapes = []
     specs = []
     for s in stats:
-        if s == "count":
-            shapes.append(jax.ShapeDtypeStruct((num_segments,), jnp.float32))
-            specs.append(full1)
-        else:
-            shapes.append(jax.ShapeDtypeStruct((num_segments, w),
+        rows = 1 if s == "count" else w
+        if k is None:
+            shapes.append(jax.ShapeDtypeStruct((rows, num_segments),
                                                jnp.float32))
-            specs.append(full2)
+            specs.append(pl.BlockSpec((rows, num_segments),
+                                      lambda *a: (0, 0)))
+        else:
+            shapes.append(jax.ShapeDtypeStruct((k, rows, num_segments),
+                                               jnp.float32))
+            specs.append(pl.BlockSpec((None, rows, num_segments),
+                                      lambda c, *a: (c, 0, 0)))
     return tuple(shapes), tuple(specs)
+
+
+def _from_kernel_layout(out: dict, lead: Tuple[int, ...], num_slots: int,
+                        num_segments: int) -> dict:
+    """Kernel outputs (``[*lead, W, S]``, count ``[*lead, 1, S]``) back to
+    the public layout ``[*lead, num_slots, num_segments(, W)]``."""
+    shaped = {}
+    for s, v in out.items():
+        if s == "count":
+            shaped[s] = v.reshape(*lead, num_slots, num_segments)
+        else:
+            v = jnp.swapaxes(v, -1, -2)
+            shaped[s] = v.reshape(*lead, num_slots, num_segments,
+                                  v.shape[-1])
+    return shaped
+
+
+def _masked_ids(segment_ids, valid):
+    """Segment ids with invalid events folded in as ``-1`` (the kernels
+    match ids against ``[0, S)``, so ``-1`` lands in no segment)."""
+    ids = segment_ids.astype(jnp.int32)
+    if valid is None:
+        return ids
+    return jnp.where(valid.astype(bool), ids, -1)
 
 
 def segment_aggregate_pallas(values: jnp.ndarray, segment_ids: jnp.ndarray,
@@ -170,41 +230,39 @@ def segment_aggregate_pallas(values: jnp.ndarray, segment_ids: jnp.ndarray,
                              stats: Tuple[str, ...] = ALL_STATS):
     """values [N, W] f32, segment_ids [N] i32 -> dict of [S, W]/[S] aggs.
 
-    N is padded to a multiple of ``block_n``; padding rows are invalid.
-    ``stats`` selects which aggregates the kernel materializes (threaded
-    through ``out_shape`` — unrequested stats are never computed).
+    N is padded to a multiple of ``block_n`` (itself a multiple of the
+    128-lane tile); padding rows are invalid. The kernel reads ids as a
+    ``[1, N]`` row and values transposed to ``[W, N]``, so every block is
+    lane-dense. ``stats`` selects which aggregates the kernel
+    materializes (threaded through ``out_shape`` — unrequested stats are
+    never computed).
     """
     stats = norm_stats(stats)
     n, w = values.shape
-    if valid is None:
-        valid = jnp.ones((n,), jnp.int32)
-    else:
-        valid = valid.astype(jnp.int32)
-    block_n = min(block_n, max(n, 8))
+    ids = _masked_ids(segment_ids, valid)
+    block_n = min(_round_up(block_n, 128), _round_up(max(n, 1), 128))
     pad = (-n) % block_n
+    vals_t = values.astype(jnp.float32).T                  # [W, N]
     if pad:
-        values = jnp.pad(values, ((0, pad), (0, 0)))
-        segment_ids = jnp.pad(segment_ids, (0, pad))
-        valid = jnp.pad(valid, (0, pad))
+        vals_t = jnp.pad(vals_t, ((0, 0), (0, pad)))
+        ids = jnp.pad(ids, (0, pad), constant_values=-1)
     n_pad = n + pad
-    grid = (n_pad // block_n,)
 
     kernel = functools.partial(_kernel, num_segments=num_segments,
-                               block_n=block_n, stats=stats)
+                               stats=stats)
     out_shapes, out_specs = _stat_outputs(stats, num_segments, w)
     outs = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_pad // block_n,),
         in_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n, w), lambda i: (i, 0)),
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
+            pl.BlockSpec((w, block_n), lambda i: (0, i)),
         ],
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
-    )(segment_ids.astype(jnp.int32), valid, values.astype(jnp.float32))
-    return dict(zip(stats, outs))
+    )(ids.reshape(1, n_pad), vals_t)
+    return {s: (o[0] if s == "count" else o.T) for s, o in zip(stats, outs)}
 
 
 def segment_aggregate_batched_pallas(values: jnp.ndarray,
@@ -232,8 +290,6 @@ def segment_aggregate_batched_pallas(values: jnp.ndarray,
     """
     stats = norm_stats(stats)
     b, n, w = values.shape
-    if valid is None:
-        valid = jnp.ones((b, n), jnp.int32)
     if slot_ids is None:
         slot_ids = jnp.arange(b, dtype=jnp.int32)
         if num_slots is None:
@@ -244,7 +300,8 @@ def segment_aggregate_batched_pallas(values: jnp.ndarray,
                  + segment_ids.astype(jnp.int32))        # [B, N]
     out = segment_aggregate_pallas(
         values.reshape(b * n, w), composite.reshape(b * n),
-        num_slots * num_segments, valid=valid.reshape(b * n),
+        num_slots * num_segments,
+        valid=None if valid is None else valid.reshape(b * n),
         block_n=block_n, interpret=interpret, stats=stats)
     shaped = {}
     for s in stats:
@@ -312,28 +369,80 @@ def segment_aggregate_batched_dense(values: jnp.ndarray,
     return out
 
 
-def _bt_kernel(table_ref, ids_ref, valid_ref, arena_ref, *out_refs,
-               num_segments: int, cap: int, stats: Tuple[str, ...],
-               num_cols: Optional[int]):
+def _bt_kernel(table_ref, ids_ref, arena_ref, *out_refs,
+               num_segments: int, stats: Tuple[str, ...],
+               num_cols: Optional[int], row_axis: int):
     """Block-table kernel body: one grid step per table row. The arena
     BlockSpec's index_map dereferences the scalar-prefetched table, so
     each step DMAs its event tile straight out of the pool arena — the
     row gather happens inside the launch, not as a host/device concat.
-    ``num_cols`` selects a value-column prefix AFTER the gather (per-tile
-    slice) so width-selecting folds never materialize an arena-wide
-    slice copy."""
+    ``num_cols`` selects a value-row prefix of the ``[W, cap]`` tile (the
+    DMA already skips all but the first 8-row group). ``row_axis`` is the
+    grid axis that walks rows: accumulators re-init when it restarts (0
+    for the plain fold; 1 for split-K, whose axis 0 walks the chunks)."""
     refs = dict(zip(stats, out_refs))
-    r = pl.program_id(0)
 
-    @pl.when(r == 0)
+    @pl.when(pl.program_id(row_axis) == 0)
     def _init():
         _init_refs(refs)
 
-    vals = arena_ref[0]
-    if num_cols is not None:
-        vals = vals[:, :num_cols]
-    _acc_tile(refs, ids_ref[0], valid_ref[0] != 0, vals,
-              num_segments, cap)
+    vals = arena_ref[...]                                 # [W', cap]
+    if num_cols is not None and num_cols < vals.shape[0]:
+        vals = vals[:num_cols]
+    _acc_tile(refs, ids_ref[...], vals, num_segments)
+
+
+def _block_table_call(values_arena, composite, table, num_slots: int,
+                      num_segments: int, stats: Tuple[str, ...],
+                      num_cols: Optional[int], interpret: bool,
+                      chunk_rows: Optional[int] = None) -> dict:
+    """Launch the block-table kernel over ``composite`` ids ``[R, cap]``
+    (invalid events already ``-1``) and return the public layout.
+
+    The kernel reads the arena as ``[P, W, cap]`` tiles, events on the
+    lanes. That is the TPU's own layout for a ``[P, cap, W]`` arena whose
+    width is not a multiple of 128 lanes (the Table-1 widths 416 and 576),
+    so the transpose below costs no relayout of the arena.
+    ``chunk_rows`` selects the split-K grid ``(k, chunk_rows)`` with one
+    partial accumulator per chunk (leading ``k`` axis on the outputs)."""
+    p, cap, w = values_arena.shape
+    w_out = num_cols if num_cols is not None else w
+    w_blk = w if num_cols is None else min(w, _round_up(num_cols, 8))
+    r = table.shape[0]
+    s_total = num_slots * num_segments
+    arena_t = jnp.swapaxes(values_arena.astype(jnp.float32), 1, 2)
+    if chunk_rows is None:
+        k = None
+        grid = (r,)
+        in_specs = [
+            pl.BlockSpec((None, 1, cap), lambda i, tbl: (i, 0, 0)),
+            pl.BlockSpec((None, w_blk, cap),
+                         lambda i, tbl: (tbl[i], 0, 0)),
+        ]
+    else:
+        k = r // chunk_rows
+        grid = (k, chunk_rows)
+        in_specs = [
+            pl.BlockSpec((None, 1, cap),
+                         lambda c, i, tbl: (c * chunk_rows + i, 0, 0)),
+            pl.BlockSpec((None, w_blk, cap),
+                         lambda c, i, tbl: (tbl[c * chunk_rows + i], 0, 0)),
+        ]
+    out_shapes, out_specs = _stat_outputs(stats, s_total, w_out, k)
+    kernel = functools.partial(_bt_kernel, num_segments=s_total,
+                               stats=stats, num_cols=num_cols,
+                               row_axis=0 if k is None else 1)
+    outs = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs),
+        out_shape=out_shapes,
+        interpret=interpret,
+    )(table.astype(jnp.int32), composite.reshape(r, 1, cap), arena_t)
+    return _from_kernel_layout(dict(zip(stats, outs)),
+                               () if k is None else (k,), num_slots,
+                               num_segments)
 
 
 def segment_aggregate_block_table_pallas(
@@ -353,53 +462,22 @@ def segment_aggregate_block_table_pallas(
     The table is a scalar-prefetch operand: grid step ``r`` DMAs arena
     row ``table[r]`` into VMEM (flash-decoding's ``block_tables`` idiom),
     so already-resident blocks are folded with zero per-batch copies.
-    ``num_cols`` restricts the fold to the leading value columns,
-    sliced per-tile inside the kernel (width-selecting operators pass
-    the FULL arena — never an arena-wide slice copy).
+    ``num_cols`` restricts the fold to the leading value columns, sliced
+    per-tile inside the kernel (width-selecting operators pass the FULL
+    arena — never an arena-wide slice copy).
     """
     stats = norm_stats(stats)
-    p, cap, w = values_arena.shape
-    w_out = num_cols if num_cols is not None else w
     r = table.shape[0]
-    if valid is None:
-        valid = jnp.ones((r, cap), jnp.int32)
     if slot_ids is None:
         slot_ids = jnp.arange(r, dtype=jnp.int32)
         if num_slots is None:
             num_slots = r
     elif num_slots is None:
         raise ValueError("num_slots is required when slot_ids is given")
-    composite = (slot_ids.astype(jnp.int32)[:, None] * num_segments
-                 + segment_ids.astype(jnp.int32))        # [R, cap]
-    s_total = num_slots * num_segments
-    kernel = functools.partial(_bt_kernel, num_segments=s_total, cap=cap,
-                               stats=stats, num_cols=num_cols)
-    out_shapes, out_specs = _stat_outputs(stats, s_total, w_out)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(r,),
-        in_specs=[
-            pl.BlockSpec((1, cap), lambda i, tbl: (i, 0)),
-            pl.BlockSpec((1, cap), lambda i, tbl: (i, 0)),
-            pl.BlockSpec((1, cap, w), lambda i, tbl: (tbl[i], 0, 0)),
-        ],
-        out_specs=out_specs,
-    )
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(table.astype(jnp.int32), composite,
-      valid.astype(jnp.int32), values_arena.astype(jnp.float32))
-    out = dict(zip(stats, outs))
-    shaped = {}
-    for s in stats:
-        if s == "count":
-            shaped[s] = out[s].reshape(num_slots, num_segments)
-        else:
-            shaped[s] = out[s].reshape(num_slots, num_segments, w_out)
-    return shaped
+    composite = _masked_ids(slot_ids.astype(jnp.int32)[:, None]
+                            * num_segments + segment_ids, valid)
+    return _block_table_call(values_arena, composite, table, num_slots,
+                             num_segments, stats, num_cols, interpret)
 
 
 def segment_aggregate_block_table_dense(
@@ -446,50 +524,6 @@ def merge_partials(partials: dict) -> dict:
         else:
             out[s] = jnp.sum(v, axis=0)
     return out
-
-
-def _stat_outputs_chunked(stats: Tuple[str, ...], k: int,
-                          num_segments: int, w: int):
-    """(out_shapes, out_specs) for the split-K kernel: the out arrays grow
-    a leading chunk axis ``[k, S(, W)]`` and chunk ``c``'s programs all map
-    to block ``c`` — each chunk's partial accumulator stays VMEM-resident
-    across its ``chunk_rows`` inner steps (grid iterates the row axis
-    fastest) and is re-initialized when the next chunk begins."""
-    full2 = pl.BlockSpec((1, num_segments, w), lambda c, r, *a: (c, 0, 0))
-    full1 = pl.BlockSpec((1, num_segments), lambda c, r, *a: (c, 0))
-    shapes = []
-    specs = []
-    for s in stats:
-        if s == "count":
-            shapes.append(jax.ShapeDtypeStruct((k, num_segments),
-                                               jnp.float32))
-            specs.append(full1)
-        else:
-            shapes.append(jax.ShapeDtypeStruct((k, num_segments, w),
-                                               jnp.float32))
-            specs.append(full2)
-    return tuple(shapes), tuple(specs)
-
-
-def _bt_splitk_kernel(table_ref, ids_ref, valid_ref, arena_ref, *out_refs,
-                      num_segments: int, cap: int, stats: Tuple[str, ...],
-                      num_cols: Optional[int]):
-    """Split-K block-table kernel body: grid ``(k, chunk_rows)``, one step
-    per (chunk, row-within-chunk). Accumulators re-init at the first row
-    of every chunk (the out BlockSpecs hand each chunk its own [1, S, W]
-    block, so ``_acc_tile``'s [S, W] tiles broadcast into it)."""
-    refs = dict(zip(stats, out_refs))
-    r = pl.program_id(1)
-
-    @pl.when(r == 0)
-    def _init():
-        _init_refs(refs)
-
-    vals = arena_ref[0]
-    if num_cols is not None:
-        vals = vals[:, :num_cols]
-    _acc_tile(refs, ids_ref[0], valid_ref[0] != 0, vals,
-              num_segments, cap)
 
 
 def _splitk_empty(stats, num_slots, num_segments, w_out, merge):
@@ -547,40 +581,11 @@ def segment_aggregate_block_table_splitk_pallas(
         segment_ids = jnp.pad(segment_ids, ((0, pad), (0, 0)))
         valid = jnp.pad(valid, ((0, pad), (0, 0)))
         slot_ids = jnp.pad(slot_ids, (0, pad))
-    k = (r + pad) // chunk_rows
-    composite = (slot_ids.astype(jnp.int32)[:, None] * num_segments
-                 + segment_ids.astype(jnp.int32))        # [R', cap]
-    s_total = num_slots * num_segments
-    kernel = functools.partial(_bt_splitk_kernel, num_segments=s_total,
-                               cap=cap, stats=stats, num_cols=num_cols)
-    out_shapes, out_specs = _stat_outputs_chunked(stats, k, s_total, w_out)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(k, chunk_rows),
-        in_specs=[
-            pl.BlockSpec((1, cap),
-                         lambda c, i, tbl: (c * chunk_rows + i, 0)),
-            pl.BlockSpec((1, cap),
-                         lambda c, i, tbl: (c * chunk_rows + i, 0)),
-            pl.BlockSpec((1, cap, w),
-                         lambda c, i, tbl: (tbl[c * chunk_rows + i], 0, 0)),
-        ],
-        out_specs=out_specs,
-    )
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(table.astype(jnp.int32), composite,
-      valid.astype(jnp.int32), values_arena.astype(jnp.float32))
-    out = dict(zip(stats, outs))
-    partials = {}
-    for s in stats:
-        if s == "count":
-            partials[s] = out[s].reshape(k, num_slots, num_segments)
-        else:
-            partials[s] = out[s].reshape(k, num_slots, num_segments, w_out)
+    composite = _masked_ids(slot_ids.astype(jnp.int32)[:, None]
+                            * num_segments + segment_ids, valid)
+    partials = _block_table_call(values_arena, composite, table, num_slots,
+                                 num_segments, stats, num_cols, interpret,
+                                 chunk_rows=chunk_rows)
     return merge_partials(partials) if merge else partials
 
 
@@ -717,9 +722,8 @@ def segment_aggregate_block_table_sharded(
     out_specs = {k: (P(axis_name, None) if k == "count"
                      else P(axis_name, None, None))
                  for k in stats}
-    # local import avoids a kernels <-> distributed cycle at module load
-    from repro.distributed.sharding import shard_map_compat
-    f = shard_map_compat(shard_fn, mesh, in_specs, out_specs)
+    f = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)
     return f(values_arena.astype(jnp.float32),
              segment_ids.astype(jnp.int32), table.astype(jnp.int32),
              valid.astype(jnp.int32), slot_ids.astype(jnp.int32))
@@ -836,9 +840,8 @@ def segment_aggregate_batched_sharded(values: jnp.ndarray,
     out_specs = {k: (P(axis_name, None) if k == "count"
                      else P(axis_name, None, None))
                  for k in stats}
-    # local import avoids a kernels <-> distributed cycle at module load
-    from repro.distributed.sharding import shard_map_compat
-    f = shard_map_compat(shard_fn, mesh, in_specs, out_specs)
+    f = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)
     return f(values.astype(jnp.float32), segment_ids.astype(jnp.int32),
              valid.astype(bool), slot_ids.astype(jnp.int32))
 
@@ -910,9 +913,8 @@ def segment_aggregate_batched_splitk_sharded(
     out_specs = {k: (P(axis_name, None, None) if k == "count"
                      else P(axis_name, None, None, None))
                  for k in stats}
-    # local import avoids a kernels <-> distributed cycle at module load
-    from repro.distributed.sharding import shard_map_compat
-    f = shard_map_compat(shard_fn, mesh, in_specs, out_specs)
+    f = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)
     partials = f(values.astype(jnp.float32), segment_ids.astype(jnp.int32),
                  valid.astype(bool), slot_ids.astype(jnp.int32))
     return merge_partials(partials)

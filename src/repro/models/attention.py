@@ -266,10 +266,8 @@ def _shard_map_dus_write(cache, new, slot, mesh, batch_axes):
     nd_tail = cache.ndim - 2
     cspec = P(bspec, "model", *([None] * nd_tail))
     nspec = P(bspec, None, *([None] * nd_tail))
-    return shd.shard_map_compat(write, mesh=mesh,
-                                in_specs=(cspec, nspec, P()),
-                                out_specs=cspec,
-                                check=False)(cache, new, slot)
+    return jax.shard_map(write, mesh=mesh, in_specs=(cspec, nspec, P()),
+                         out_specs=cspec, check_vma=False)(cache, new, slot)
 
 
 def attn_decode(params, x, cfg: ModelConfig, *, cache_k, cache_v, cache_pos,

@@ -132,13 +132,13 @@ def moe_forward(params, x, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
                           capacity, cfg.mlp_variant)
         return jax.lax.psum(out, axis_name="model")
 
-    y = shd.shard_map_compat(
+    y = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(bspec, bspec, bspec,
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=bspec,
-        check=False,
+        check_vma=False,
     )(x_flat, idx_flat, wts_flat,
       params["wg"].astype(cd), params["wu"].astype(cd),
       params["wd"].astype(cd))

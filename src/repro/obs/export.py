@@ -6,6 +6,8 @@ import contextlib
 import json
 from typing import Dict
 
+import jax
+
 from .registry import Histogram, MetricsRegistry, _HistogramChild
 
 __all__ = ["to_prometheus", "to_json", "profiler_annotation"]
@@ -61,18 +63,11 @@ def to_json(registry: MetricsRegistry, indent=None) -> str:
 
 @contextlib.contextmanager
 def profiler_annotation(name: str, enabled: bool = True):
-    """Wrap a region in ``jax.profiler.TraceAnnotation`` when available.
-
-    No-op when disabled or when jax/profiler is unimportable, so callers can
-    wrap fold launches unconditionally and gate with a config knob.
-    """
+    """Wrap a region in ``jax.profiler.TraceAnnotation``; a no-op when
+    disabled, so callers can wrap fold launches unconditionally and gate
+    with a config knob."""
     if not enabled:
         yield
         return
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:  # pragma: no cover - depends on jax build
-        yield
-        return
-    with TraceAnnotation(name):
+    with jax.profiler.TraceAnnotation(name):
         yield

@@ -35,6 +35,19 @@ def test_alloc_free_cycle_and_exhaustion():
     assert pool.alloc() == slots[0]
 
 
+def test_commit_snapshots_host_arrays():
+    """A pooled slot is a copy: the host arrays it came from stay
+    mutable (ingest appends to a host block's tail), and a later write
+    to them must never reach the arena."""
+    pool = DeviceBlockPool(2, CAP, W)
+    b = _block(3, fill=CAP // 2)
+    pool.commit(b, pool.alloc(), b.host_data)
+    b.host_data["values"][:] = 99.0
+    b.host_data["keys"][:] = 7
+    got = pool.read_host(b)
+    assert np.all(got["values"] == 3.0) and np.all(got["keys"] == 3)
+
+
 def test_sharded_ranges_no_cross_shard_stealing():
     pool = DeviceBlockPool(8, CAP, W, num_shards=4)
     assert pool.slots_per_shard == 2

@@ -111,6 +111,35 @@ def test_storage_spill_roundtrip(tmp_path):
     io.shutdown()
 
 
+def test_spill_keeps_host_copy_of_block_that_grew_mid_spill(tmp_path):
+    """Ingest may append to a host block's tail while the I/O thread
+    spills it. The record written before the append misses the new
+    events, so the spill must keep the host copy (and spill it again
+    later) instead of dropping it."""
+    from repro.storage import make_store
+    store = make_store("log", tmp_path)
+    io = IOScheduler(MemoryBudget(10 << 20), store=store)
+    st = WindowState(0, 10, width=2, block_capacity=32)
+    st.append_events(_batch(20), late=False)
+    blk = st.blocks[0]
+    late = _batch(5, seed=1)
+    commit = store.commit
+
+    def commit_then_append(*a, **kw):
+        out = commit(*a, **kw)
+        st.append_events(late, late=True)     # between put and finalize
+        return out
+    store.commit = commit_then_append
+    io.spill_blocks_sync([blk])
+    assert blk.tier == Tier.HOST and blk.fill == 25
+    store.commit = commit
+    io.spill_blocks_sync([blk])
+    assert blk.tier == Tier.STORAGE
+    np.testing.assert_array_equal(blk.as_event_batch().values[20:],
+                                  late.values)
+    io.shutdown()
+
+
 def test_drop_removes_storage_file(tmp_path):
     budget = MemoryBudget(10 << 20)
     io = IOScheduler(budget, spill_dir=tmp_path)

@@ -31,7 +31,9 @@ from repro.core.operators import make_operator
 from repro.core.triggers import DeltaTTrigger
 from repro.core.windows import WindowId
 from repro.distributed.fault import EngineRecovery
-from repro.testing import FaultInjector, FaultyBlockStore
+from repro.testing import (
+    FaultInjector, FaultyBlockStore, oracle_average, oracle_stock,
+)
 
 #: store ops the chaos axis injects on. Deliberately NOT ``delete``:
 #: purges/reconciles run on the engine main thread outside the retry
@@ -256,38 +258,6 @@ def _drive(op_name: str, batched: bool, sharded: bool, spill_dir,
     return results, (keys, tss, vals), totals
 
 
-def _oracle_average(keys, ts, vals):
-    """Never-spilling in-memory oracle: exact mean over ALL events of
-    each tumbling window."""
-    wstart = np.floor(ts / WINDOW) * WINDOW
-    out = {}
-    for s in np.unique(wstart):
-        sel = wstart == s
-        out[WindowId(float(s), float(s) + WINDOW)] = \
-            float(np.mean(vals[sel, 0], dtype=np.float64))
-    return out
-
-
-def _oracle_stock(keys, ts, vals, num_keys: int = 8):
-    wstart = np.floor(ts / WINDOW) * WINDOW
-    out = {}
-    for s in np.unique(wstart):
-        sel = wstart == s
-        k = keys[sel] % num_keys
-        p = vals[sel, 0].astype(np.float64)
-        mn = np.full(num_keys, np.inf)
-        mx = np.full(num_keys, -np.inf)
-        sm = np.zeros(num_keys)
-        ct = np.zeros(num_keys)
-        np.minimum.at(mn, k, p)
-        np.maximum.at(mx, k, p)
-        np.add.at(sm, k, p)
-        np.add.at(ct, k, 1.0)
-        out[WindowId(float(s), float(s) + WINDOW)] = {
-            "mean": sm / np.maximum(ct, 1.0), "min": mn, "max": mx}
-    return out
-
-
 @pytest.mark.parametrize("batched,sharded,pooled,store", [
     # the default persistent tier is the log-structured store
     (True, True, True, "log"), (True, False, True, "log"),  # block table
@@ -305,7 +275,7 @@ def test_soak_differential_average(tmp_path, batched, sharded, pooled,
                                    store):
     results, (keys, ts, vals), totals = _drive(
         "average", batched, sharded, tmp_path, pooled=pooled, store=store)
-    want = _oracle_average(keys, ts, vals)
+    want = oracle_average(keys, ts, vals, WINDOW)
     assert set(results) == set(want)
     for wid in want:
         assert results[wid] == pytest.approx(want[wid], rel=2e-4,
@@ -338,7 +308,7 @@ def test_soak_differential_stock_spill_pressure(tmp_path, sharded, pooled):
     sharded."""
     results, (keys, ts, vals), totals = _drive(
         "stock", True, sharded, tmp_path, width=1, pooled=pooled)
-    want = _oracle_stock(keys, ts, vals)
+    want = oracle_stock(keys, ts, vals, WINDOW, num_keys=8)
     assert set(results) == set(want)
     for wid, w in want.items():
         got = results[wid]
@@ -366,7 +336,7 @@ def test_soak_differential_pipelined(tmp_path, pooled):
     results, (keys, ts, vals), totals = _drive(
         "average", True, False, tmp_path, pooled=pooled,
         pipelined=True)
-    want = _oracle_average(keys, ts, vals)
+    want = oracle_average(keys, ts, vals, WINDOW)
     assert set(results) == set(want)
     for wid in want:
         assert results[wid] == pytest.approx(want[wid], rel=2e-4,
@@ -393,7 +363,7 @@ def test_soak_differential_learned_prefetch(tmp_path, batched, pipelined):
     results, (keys, ts, vals), totals = _drive(
         "average", batched, False, tmp_path, pipelined=pipelined,
         prefetch="learned")
-    want = _oracle_average(keys, ts, vals)
+    want = oracle_average(keys, ts, vals, WINDOW)
     assert set(results) == set(want)
     for wid in want:
         assert results[wid] == pytest.approx(want[wid], rel=2e-4,
@@ -426,7 +396,7 @@ def test_soak_differential_splitk(tmp_path, sharded, pooled, splitk):
     results, (keys, ts, vals), totals = _drive(
         "stock", True, sharded, tmp_path, width=1, pooled=pooled,
         splitk=splitk)
-    want = _oracle_stock(keys, ts, vals)
+    want = oracle_stock(keys, ts, vals, WINDOW, num_keys=8)
     assert set(results) == set(want)
     for wid, w in want.items():
         got = results[wid]
@@ -480,7 +450,7 @@ def test_soak_differential_chaos_faults(tmp_path, pipelined):
     results, (keys, ts, vals), totals = _drive(
         "average", True, False, tmp_path, pooled=True,
         pipelined=pipelined, fault_rate=0.25, fault_seed=77)
-    want = _oracle_average(keys, ts, vals)
+    want = oracle_average(keys, ts, vals, WINDOW)
     # oracle parity: identical window set, identical answers
     assert set(results) == set(want)
     for wid in want:
@@ -630,7 +600,7 @@ def test_soak_differential_chaos_restart(tmp_path):
     keys = np.concatenate([k for k, _, _ in all_events])
     tss = np.concatenate([t for _, t, _ in all_events])
     vals = np.concatenate([v for _, _, v in all_events])
-    want = _oracle_average(keys, tss, vals)
+    want = oracle_average(keys, tss, vals, WINDOW)
     assert set(results) == set(want)            # zero lost windows
     for wid in want:
         assert results[wid] == pytest.approx(want[wid], rel=2e-4,
