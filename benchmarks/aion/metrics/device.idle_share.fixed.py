@@ -1,0 +1,6 @@
+"""``device.idle_share``, read in the open-loop cell, where it moves the
+staleness of late results."""
+
+from harness import reader
+
+read = reader("device.idle_share")

@@ -1,0 +1,6 @@
+"""``engine.control_self_share``, read in the open-loop cell, where it moves
+the staleness of late results."""
+
+from harness import reader
+
+read = reader("engine.control_self_share")

@@ -1,0 +1,6 @@
+"""``fold.compiles``, read in the open-loop cell, where it moves the staleness
+of late results."""
+
+from harness import reader
+
+read = reader("fold.compiles")

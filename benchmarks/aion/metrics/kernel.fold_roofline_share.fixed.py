@@ -1,0 +1,6 @@
+"""``kernel.fold_roofline_share``, read in the open-loop cell, where it moves
+the staleness of late results."""
+
+from harness import reader
+
+read = reader("kernel.fold_roofline_share")

@@ -1,0 +1,6 @@
+"""``pool.fallback_row_share``, read in the open-loop cell, where it moves the
+staleness of late results."""
+
+from harness import reader
+
+read = reader("pool.fallback_row_share")
