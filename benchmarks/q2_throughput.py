@@ -71,8 +71,8 @@ def run_one(workload, baseline: bool, include_late: bool) -> Dict:
         + eng.metrics.late_executions,
         "fetch_stall_s": round(eng.metrics.fetch_stall_seconds, 4),
         "batch_occupancy": round(eng.metrics.mean_batch_occupancy, 2),
-        "device_s_per_exec": round(
-            eng.metrics.device_seconds_per_execution, 6),
+        "dispatch_s_per_exec": round(
+            eng.metrics.dispatch_seconds_per_execution, 6),
     }
 
 
@@ -85,7 +85,8 @@ def fold_benchmark(num_windows: int = 8, events_per_window: int = 2000,
     """Fold throughput with ``num_windows`` concurrent due windows:
     batched single-pass execution vs the per-window reference path.
     Reports events folded per second of execution wall time, batch
-    occupancy, and device time per window execution.
+    occupancy, and host seconds dispatching the fold per window
+    execution.
 
     ``modes`` rows are ``(label, batched_execution, slot_sharding)`` —
     the ``--devices N`` sweep adds a slot-sharded mode that partitions
@@ -135,7 +136,7 @@ def fold_benchmark(num_windows: int = 8, events_per_window: int = 2000,
                 rng.normal(size=(n, 1)).astype(np.float32))
 
         # warmup round compiles the fold(s); reset counters so reported
-        # device time reflects steady state, not compilation
+        # dispatch time reflects steady state, not compilation
         eng.ingest(round_events(0), now=0.0)
         eng.advance_watermark(horizon, now=horizon)
         m = eng.metrics
@@ -143,7 +144,7 @@ def fold_benchmark(num_windows: int = 8, events_per_window: int = 2000,
         m.batch_executions = 0
         m.batched_windows = 0
         m.sharded_batch_executions = 0
-        m.batch_device_seconds = 0.0
+        m.batch_dispatch_seconds = 0.0
         m.batch_occupancy_series.clear()
         times = []
         for r in range(1, repeats + 1):
@@ -158,7 +159,8 @@ def fold_benchmark(num_windows: int = 8, events_per_window: int = 2000,
             "exec_wall_s": round(sum(times), 4),
             "windows_executed": m.live_executions,
             "batch_occupancy": round(m.mean_batch_occupancy, 2),
-            "device_s_per_exec": round(m.device_seconds_per_execution, 6),
+            "dispatch_s_per_exec": round(
+                m.dispatch_seconds_per_execution, 6),
             "sharded_passes": m.sharded_batch_executions,
         }
         eng.close()
@@ -255,7 +257,7 @@ def gather_benchmark(num_windows: int = 8, events_per_window: int = 8000,
             late_batch(r - warmup)
         m = eng.metrics
         m.batch_gather_seconds = 0.0
-        m.batch_device_seconds = 0.0
+        m.batch_dispatch_seconds = 0.0
         m.batch_stall_seconds = 0.0
         m.pooled_rows = m.fallback_rows = m.demand_pool_fills = 0
         # steady state: re-execute the same due set repeatedly (the
@@ -266,7 +268,7 @@ def gather_benchmark(num_windows: int = 8, events_per_window: int = 8000,
         wall = time.time() - t0
         out = {
             "gather_s": round(m.batch_gather_seconds, 6),
-            "fold_s": round(m.batch_device_seconds, 6),
+            "fold_s": round(m.batch_dispatch_seconds, 6),
             "stall_s": round(m.batch_stall_seconds, 6),
             "wall_s": round(wall, 6),
             "fold_events_per_sec": round(n * repeats / max(wall, 1e-9)),
@@ -507,7 +509,7 @@ def skew_benchmark(num_windows: int = 8, rounds: int = 10,
         late_batch(-1)                                 # warm the late path
         m = eng.metrics
         cache0 = eng.observability()["fold"]["cache_size"]
-        m.batch_device_seconds = 0.0
+        m.batch_dispatch_seconds = 0.0
         m.pooled_rows = 0
         launches0 = m.splitk_launches
         rows_folded = 0
@@ -518,11 +520,11 @@ def skew_benchmark(num_windows: int = 8, rounds: int = 10,
             rows_folded += int(have.sum())
         wall = time.time() - t0
         out = {
-            "fold_s": round(m.batch_device_seconds, 6),
+            "fold_s": round(m.batch_dispatch_seconds, 6),
             "wall_s": round(wall, 6),
             "rows_folded": rows_folded,
             "fold_rows_per_sec": round(
-                rows_folded / max(m.batch_device_seconds, 1e-9)),
+                rows_folded / max(m.batch_dispatch_seconds, 1e-9)),
             "recompiles": eng.observability()["fold"]["cache_size"]
             - cache0,
             "splitk_launches": m.splitk_launches - launches0,

@@ -403,11 +403,13 @@ class AionConfig:
     # poisons the pipeline
     fold_round_retry: bool = True
     # ---- observability layer (ISSUE 10) ------------------------------
-    # fraction of root spans (ingest / watermark_advance / poll) that
-    # are traced; children (fold rounds, I/O tasks) inherit the parent's
-    # decision. 0.0 keeps tracing entirely off the hot path (every span
-    # is the shared no-op NULL_SPAN); 1.0 traces everything and must
-    # stay under 5% fold-throughput overhead (see `make bench-obs`)
+    # fraction of root spans (ingest / watermark_advance / poll, and
+    # execute_window when called directly) sampled into the trace ring;
+    # children (fold rounds, execution phases, I/O tasks, store reads,
+    # arena fills) inherit the parent's decision. 0.0 with profiler_annotations off keeps
+    # tracing entirely off the hot path (every span is the shared no-op
+    # NULL_SPAN). 1.0 traces everything; `make bench-obs` measured its
+    # overhead on fold throughput on the CPU only (under 5%)
     trace_sample_rate: float = 0.0
     # finished spans are kept in a bounded ring buffer of this many
     # records; oldest are dropped (counted in tracer stats)
@@ -415,9 +417,9 @@ class AionConfig:
     # default format for engine.observability(export=...): "json" or
     # "prometheus"
     metrics_export: str = "json"
-    # wrap fold launches in jax.profiler.TraceAnnotation so device
-    # traces line up with engine spans (no-op if the profiler is
-    # unavailable)
+    # profiler sink of the tracer: every span the engine opens, sampled
+    # or not, is also a jax.profiler.TraceAnnotation named aion.<span>,
+    # so a profiler trace holds the engine's spans on the device's clock
     profiler_annotations: bool = False
     # cap on StoreHealth.transitions / EngineMetrics.ladder_transitions
     # (BoundedSeries; sheds oldest half at the cap)

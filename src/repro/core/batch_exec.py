@@ -81,7 +81,6 @@ from repro.core.windows import WindowId
 from repro.kernels.segment_aggregate import (
     next_pow2, pack_rows_shard_major,
 )
-from repro.obs import profiler_annotation
 
 
 # largest split-K launch group, in chunks: greedy pow2 decomposition of a
@@ -126,6 +125,16 @@ def snapshot_block_partition(state: WindowState):
     m_ids = {id(b) for b in m_snapshot}
     p_blocks = [b for b in state.blocks if id(b) not in m_ids]
     return m_snapshot, p_blocks
+
+
+def tier_counts(*block_lists) -> Dict[str, int]:
+    """Blocks by tier (``device`` / ``host`` / ``storage``) over the
+    given lists: what an execution record says it folded from where."""
+    counts = {t.value: 0 for t in Tier}
+    for blocks in block_lists:
+        for b in blocks:
+            counts[b.tier.value] += 1
+    return counts
 
 
 def plan_slot_placement(num_windows: int, num_devices: int
@@ -230,7 +239,8 @@ class BatchExecutor:
         if not items:
             return {}
         if not op.supports_batch or len(items) == 1:
-            return {it.wid: eng.execute_window(it.wid, now, it.late)
+            return {it.wid: eng.execute_window(it.wid, now, it.late,
+                                               trace_parent=trace_parent)
                     for it in items}
 
         span = eng.tracer.child(
@@ -249,6 +259,7 @@ class BatchExecutor:
             demoted0 = eng.metrics.epoch_demoted_rows
 
         with span:
+            t0_ns = _time.time_ns()
             t0 = _time.time()
             # the round's lease on its windows: destages queued before it
             # yield instead of undoing its demand fills mid-round
@@ -260,22 +271,20 @@ class BatchExecutor:
                 #    whatever tier it moves to while the batch assembles)
                 plans = [(it, sum(snapshot_block_partition(it.state), []))
                          for it in items]
+                tiers = [tier_counts(blocks) for _, blocks in plans] \
+                    if eng.tracer.enabled else ()
 
                 mesh = self._slot_mesh()
                 num_devices = mesh.size if mesh is not None else 1
 
-                with profiler_annotation(
-                        f"aion.fold_round[{len(items)}]",
-                        enabled=getattr(eng.aion, "profiler_annotations",
-                                        False)):
-                    if eng.pool is not None:
-                        results, slot_of, num_slots, dev_dt, gather_dt, \
-                            ran_sharded = self._fold_pooled(plans, mesh,
-                                                            num_devices)
-                    else:
-                        results, slot_of, num_slots, dev_dt, gather_dt, \
-                            ran_sharded = self._fold_stacked(plans, mesh,
-                                                             num_devices)
+                if eng.pool is not None:
+                    results, slot_of, num_slots, dispatch_dt, gather_dt, \
+                        ran_sharded = self._fold_pooled(plans, mesh,
+                                                        num_devices)
+                else:
+                    results, slot_of, num_slots, dispatch_dt, gather_dt, \
+                        ran_sharded = self._fold_stacked(plans, mesh,
+                                                         num_devices)
             finally:
                 for it in items:
                     it.state.folding -= 1
@@ -295,12 +304,18 @@ class BatchExecutor:
                 out[it.wid] = result
                 eng._post_execute_destage(it.wid, it.state, now)
             eng.metrics.exec_seconds += _time.time() - t0
+            t1_ns = _time.time_ns()
+            for (it, _), blocks in zip(plans, tiers):
+                eng.metrics.executions.append({
+                    "window": it.wid.start, "late": it.late,
+                    "path": "round", "t0": t0_ns, "t1": t1_ns,
+                    "blocks": blocks})
             eng.metrics.batch_executions += 1
             eng.metrics.batched_windows += len(plans)
-            eng.metrics.batch_device_seconds += dev_dt
+            eng.metrics.batch_dispatch_seconds += dispatch_dt
             eng.metrics.batch_gather_seconds += gather_dt
             eng.metrics.batch_occupancy_series.append(len(plans))
-            eng.metrics.fold_seconds.observe(dev_dt)
+            eng.metrics.fold_seconds.observe(dispatch_dt)
             if ran_sharded:
                 eng.metrics.sharded_batch_executions += 1
             if span.sampled:
@@ -313,7 +328,7 @@ class BatchExecutor:
                         eng.metrics.epoch_demoted_rows - demoted0),
                     recompiled=bool(cache1 > cache0),
                     sharded=ran_sharded,
-                    device_seconds=round(dev_dt, 6),
+                    dispatch_seconds=round(dispatch_dt, 6),
                     gather_seconds=round(gather_dt, 6))
                 span.event("emit", results=len(out))
         return out
@@ -372,7 +387,7 @@ class BatchExecutor:
                            accs):
         """Dispatch every launch group against one arena snapshot; the
         group accumulators append to ``accs`` (merged in the shared
-        tail). Returns the device seconds spent."""
+        tail). Returns the host seconds spent dispatching them."""
         eng = self.engine
         op = eng.operator
         d0 = _time.time()
@@ -482,17 +497,17 @@ class BatchExecutor:
                 rows.append((arrs, blk.fill, slot_of[i]))
 
         ran_sharded = False
-        dev_dt = 0.0
+        dispatch_dt = 0.0
         if rows:
             data, fills, slots = self._stack_rows(rows, num_devices,
                                                   slots_per,
                                                   balance=balanced)
             gather_dt = _time.time() - g0
-            dev_t0 = _time.time()
+            dispatch_t0 = _time.time()
             results = op.run_batch(data, fills, slots, num_slots,
                                    mesh=mesh,
                                    splitk=chunk if balanced else 0)
-            dev_dt = _time.time() - dev_t0
+            dispatch_dt = _time.time() - dispatch_t0
             ran_sharded = mesh is not None
             if balanced:
                 eng.metrics.splitk_launches += 1
@@ -500,7 +515,7 @@ class BatchExecutor:
             gather_dt = _time.time() - g0
             # every window empty: finalize the identity accumulator
             results = [op.finalize(op.init_acc()) for _ in range(num_slots)]
-        return results, slot_of, num_slots, dev_dt, gather_dt, ran_sharded
+        return results, slot_of, num_slots, dispatch_dt, gather_dt, ran_sharded
 
     # ------------------------------------------------------- pooled gather
     def _pack_table(self, rows, num_devices: int, slots_per: int):
@@ -553,7 +568,7 @@ class BatchExecutor:
 
         g0 = _time.time()
         gather_dt = 0.0
-        dev_dt = 0.0
+        dispatch_dt = 0.0
         blocks: List[Tuple[Any, int]] = []        # (block, window index)
         for i, (it, blks) in enumerate(plans):
             for blk in blks:
@@ -652,7 +667,7 @@ class BatchExecutor:
                             pooled, num_devices, slots_per, chunk)
                         arena_data = {"keys": k_arena, "values": v_arena}
                         gather_dt += _time.time() - g0
-                        dev_dt += self._fold_table_groups(
+                        dispatch_dt += self._fold_table_groups(
                             groups, arena_data, num_slots, use_mesh,
                             accs)
                         ran_sharded = ran_sharded or use_mesh is not None
@@ -660,7 +675,7 @@ class BatchExecutor:
                     else:
                         gather_dt += _time.time() - g0
             return self._fold_pooled_tail(
-                plans, accs, fallback, slot_of, num_slots, dev_dt,
+                plans, accs, fallback, slot_of, num_slots, dispatch_dt,
                 gather_dt, ran_sharded, chunk)
 
         # the whole batch runs under ONE pool pin: any fill that lands
@@ -707,9 +722,8 @@ class BatchExecutor:
                 groups = self._plan_table_groups(pooled, num_devices,
                                                  slots_per, chunk)
                 gather_dt += _time.time() - g0
-                dev_dt += self._fold_table_groups(groups, arena_data,
-                                                  num_slots, use_mesh,
-                                                  accs)
+                dispatch_dt += self._fold_table_groups(
+                    groups, arena_data, num_slots, use_mesh, accs)
                 ran_sharded = ran_sharded or use_mesh is not None
                 eng.metrics.pooled_rows += len(pooled)
 
@@ -738,17 +752,17 @@ class BatchExecutor:
                         staged, num_devices, slots_per, chunk)
                     arena2 = {"keys": k2, "values": v2}
                     gather_dt += _time.time() - g0
-                    dev_dt += self._fold_table_groups(
+                    dispatch_dt += self._fold_table_groups(
                         groups, arena2, num_slots, use_mesh, accs)
                     ran_sharded = ran_sharded or use_mesh is not None
                     eng.metrics.pooled_rows += len(staged)
 
         return self._fold_pooled_tail(plans, accs, fallback, slot_of,
-                                      num_slots, dev_dt, gather_dt,
+                                      num_slots, dispatch_dt, gather_dt,
                                       ran_sharded, chunk)
 
     def _fold_pooled_tail(self, plans, accs, fallback, slot_of, num_slots,
-                          dev_dt, gather_dt, ran_sharded, chunk=0):
+                          dispatch_dt, gather_dt, ran_sharded, chunk=0):
         """Shared tail of both pooled pin strategies: fold the fallback
         rows through the stacked gather, then merge the partial
         accumulators into per-slot results. Under a split-K round
@@ -780,7 +794,7 @@ class BatchExecutor:
                     d0 = _time.time()
                     accs.append(op.fold_batch(data, fills, slots,
                                               num_slots, mesh=None))
-                    dev_dt += _time.time() - d0
+                    dispatch_dt += _time.time() - d0
                     g0 = _time.time()
                 eng.metrics.fallback_rows += len(rows)
             else:
@@ -795,5 +809,5 @@ class BatchExecutor:
             for a in accs[1:]:
                 acc = op.merge_acc(acc, a)
             results = op.finalize_batch(acc, num_slots)
-            dev_dt += _time.time() - d0
-        return results, slot_of, num_slots, dev_dt, gather_dt, ran_sharded
+            dispatch_dt += _time.time() - d0
+        return results, slot_of, num_slots, dispatch_dt, gather_dt, ran_sharded

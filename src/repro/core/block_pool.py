@@ -57,6 +57,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
@@ -65,7 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.obs import MetricsRegistry, StatsMap
+from repro.obs import MetricsRegistry, StatsMap, Tracer
 
 
 def _write_fn(k_arena, v_arena, slot, keys, values):
@@ -106,7 +107,7 @@ class DeviceBlockPool:
                  num_shards: int = 1,
                  max_arena_bytes: Optional[int] = None,
                  registry: Optional[MetricsRegistry] = None,
-                 mesh=None):
+                 mesh=None, tracer: Optional[Tracer] = None):
         if mesh is not None:
             num_shards = mesh.size
         num_shards = max(int(num_shards), 1)
@@ -171,7 +172,12 @@ class DeviceBlockPool:
         self.stats.register_many([
             "allocs", "frees", "exhausted", "writes",
             "copy_writes", "deferred_fills",
-            "batched_fill_commits", "epoch_bumps"])
+            "batched_fill_commits", "epoch_bumps",
+            # host seconds inside arena fills: per-block commits and
+            # batched flushes, host copies and dispatch included
+            "fill_seconds"])
+        # ``pool.fill`` spans go to the engine's tracer (off by default)
+        self.tracer = tracer if tracer is not None else Tracer()
         # occupancy gauges are cheaper polled than maintained: the
         # registry snapshot calls back into the pool under its lock
         registry.register_callback(lambda: {
@@ -215,24 +221,38 @@ class DeviceBlockPool:
         live), donated otherwise."""
         if not self._pending:
             return
-        slots = list(self._pending)
-        # pad the batch to a power of two by repeating the first entry
-        # (same slot, same data: an idempotent duplicate scatter row) so
-        # the jitted scatter sees O(log) distinct shapes
-        n = 1
-        while n < len(slots):
-            n <<= 1
-        slots = slots + [slots[0]] * (n - len(slots))
-        ks = np.stack([self._pending[s][0] for s in slots])
-        vs = np.stack([self._pending[s][1] for s in slots])
-        idx = np.asarray(slots, np.int32)
-        scatter = _scatter_jit if self._pins else _scatter_donated_jit
-        if self._pins:
-            self.stats.inc("copy_writes")
-        self.keys, self.values = scatter(self.keys, self.values, idx,
-                                         ks, vs)
+        with self._filling(len(self._pending)):
+            slots = list(self._pending)
+            # pad the batch to a power of two by repeating the first
+            # entry (same slot, same data: an idempotent duplicate
+            # scatter row) so the jitted scatter sees O(log) distinct
+            # shapes
+            n = 1
+            while n < len(slots):
+                n <<= 1
+            slots = slots + [slots[0]] * (n - len(slots))
+            ks = np.stack([self._pending[s][0] for s in slots])
+            vs = np.stack([self._pending[s][1] for s in slots])
+            idx = np.asarray(slots, np.int32)
+            scatter = _scatter_jit if self._pins else _scatter_donated_jit
+            if self._pins:
+                self.stats.inc("copy_writes")
+            self.keys, self.values = scatter(self.keys, self.values, idx,
+                                             ks, vs)
         self.stats.inc("batched_fill_commits")
         self._pending.clear()
+
+    @contextlib.contextmanager
+    def _filling(self, blocks: int):
+        """One arena fill of ``blocks`` blocks: a ``pool.fill`` span
+        under the filling thread's own, timed into
+        ``stats['fill_seconds']``."""
+        t0 = time.perf_counter()
+        try:
+            with self.tracer.inner("pool.fill", blocks=blocks):
+                yield
+        finally:
+            self.stats.inc("fill_seconds", time.perf_counter() - t0)
 
     @contextlib.contextmanager
     def pinned(self):
@@ -327,28 +347,30 @@ class DeviceBlockPool:
         ``device_put`` path). Ingest appends to a host block only under
         ``block.lock`` too, so the copy taken here holds exactly the
         events the block's fill counts."""
-        # private copies: the host arrays stay mutable (ingest appends to
-        # a host block's tail) and a device transfer may read its source
-        # after this returns, or alias it outright
-        keys = np.array(host_data["keys"], np.int32)
-        vals = np.array(host_data["values"], np.float32)
-        with self._lock:
-            if self._deferred:
-                # a fold round's fills batch into one host stack, one
-                # transfer and one scatter at the next snapshot/read
-                # (see ``deferred_fills``)
-                self._pending[slot] = (keys, vals)
-                self.stats.inc("deferred_fills")
-            else:
-                write = _write_jit if self._pins else _write_donated_jit
-                if self._pins:
-                    self.stats.inc("copy_writes")
-                self.keys, self.values = write(self.keys, self.values,
-                                               slot, keys, vals)
-            block.pool_slot = slot
-            block.pool = self
-            self._bump_epoch_locked(slot)
-            self.stats.inc("writes")
+        with self._filling(1):
+            # private copies: the host arrays stay mutable (ingest
+            # appends to a host block's tail) and a device transfer may
+            # read its source after this returns, or alias it outright
+            keys = np.array(host_data["keys"], np.int32)
+            vals = np.array(host_data["values"], np.float32)
+            with self._lock:
+                if self._deferred:
+                    # a fold round's fills batch into one host stack, one
+                    # transfer and one scatter at the next snapshot/read
+                    # (see ``deferred_fills``)
+                    self._pending[slot] = (keys, vals)
+                    self.stats.inc("deferred_fills")
+                else:
+                    write = _write_jit if self._pins \
+                        else _write_donated_jit
+                    if self._pins:
+                        self.stats.inc("copy_writes")
+                    self.keys, self.values = write(self.keys, self.values,
+                                                   slot, keys, vals)
+                block.pool_slot = slot
+                block.pool = self
+                self._bump_epoch_locked(slot)
+                self.stats.inc("writes")
 
     def slot_epochs(self, blocks) -> List[Tuple[Optional[int], int]]:
         """One consistent ``(pool_slot, epoch)`` read per block — NO

@@ -35,7 +35,7 @@ import numpy as np
 
 from repro.configs.base import AionConfig
 from repro.core.batch_exec import (
-    BatchExecutor, BatchWorkItem, snapshot_block_partition,
+    BatchExecutor, BatchWorkItem, snapshot_block_partition, tier_counts,
 )
 from repro.core.buckets import Block, MemoryBudget, Tier, WindowState
 from repro.core.cleanup import PredictiveCleanup
@@ -81,7 +81,8 @@ class EngineMetrics:
         "batch_executions": "counter", "batched_windows": "counter",
         # device passes that ran slot-sharded across a multi-device mesh
         "sharded_batch_executions": "counter",
-        "batch_device_seconds": "counter",
+        # host seconds dispatching the fold launches (not device time)
+        "batch_dispatch_seconds": "counter",
         # batch assembly outside the fold call (row stack / table build)
         "batch_gather_seconds": "counter",
         # waiting on overlapped demand pool-fills (I/O the fold hid)
@@ -130,9 +131,15 @@ class EngineMetrics:
         d["batch_occupancy_series"] = BoundedSeries(series_max)
         d["device_bytes_series"] = BoundedSeries(series_max)
         d["host_bytes_series"] = BoundedSeries(series_max)
-        # fold-round latency histogram (observed by the batch executor)
+        # one record per window execution, single-window and batched,
+        # while tracing is on (``profiler_annotations`` or a sample rate):
+        # {"window", "late", "path" ("single" | "round"), "t0", "t1"
+        # (time.time_ns()), "blocks": {tier: blocks at the snapshot}}
+        d["executions"] = BoundedSeries(series_max)
+        # fold-round dispatch histogram (observed by the batch executor)
         d["fold_seconds"] = registry.histogram(
-            "aion_fold_round_seconds", "device seconds per fold round",
+            "aion_fold_round_seconds",
+            "host seconds to dispatch a fold round's launches",
             labelnames=("tenant",)).labels(tenant)
 
     def __getattr__(self, name):
@@ -174,10 +181,10 @@ class EngineMetrics:
         return float(np.mean(self.batch_occupancy_series))
 
     @property
-    def device_seconds_per_execution(self) -> float:
+    def dispatch_seconds_per_execution(self) -> float:
         if not self.batched_windows:
             return 0.0
-        return self.batch_device_seconds / self.batched_windows
+        return self.batch_dispatch_seconds / self.batched_windows
 
 
 @dataclass
@@ -229,7 +236,8 @@ class StreamEngine:
             self.registry = MetricsRegistry()
             self.tracer = Tracer(
                 sample_rate=self.aion.trace_sample_rate,
-                capacity=self.aion.trace_ring_max)
+                capacity=self.aion.trace_ring_max,
+                profile=self.aion.profiler_annotations)
             # persistent tier of the p-bucket: an explicit BlockStore,
             # or one built from the config backend under spill_dir
             # ('log' by default — the legacy file-per-block npz backend
@@ -241,7 +249,7 @@ class StreamEngine:
                     segment_bytes=self.aion.store_segment_bytes,
                     sim_spb=simulated_seconds_per_byte,
                     readahead_bytes=self.aion.store_readahead_bytes,
-                    registry=self.registry)
+                    registry=self.registry, tracer=self.tracer)
             self.store = store
             self.budget = MemoryBudget(device_budget_bytes)
             # persistent device block pool: staging becomes arena fills
@@ -275,7 +283,7 @@ class StreamEngine:
                     self.aion.pool_slots, self.aion.block_size,
                     value_width, mesh=mesh,
                     max_arena_bytes=device_budget_bytes // 2,
-                    registry=self.registry)
+                    registry=self.registry, tracer=self.tracer)
                 if pool.pool_slots > 0 \
                         and self.budget.try_reserve(pool.arena_bytes):
                     self.pool = pool
@@ -539,6 +547,11 @@ class StreamEngine:
         span = (self.tracer.child(trace_parent, "watermark_advance", wm=wm)
                 if trace_parent is not None
                 else self.tracer.root("watermark_advance", wm=wm))
+        with span:
+            self._expire_due(wm, now, span)
+
+    def _expire_due(self, wm: float, now: float, span) -> None:
+        """Execute every window the watermark ``wm`` closed."""
         due = [wid for wid in sorted(self.windows)
                if not self.windows[wid].expired and wid.end <= wm]
         if span.sampled:
@@ -582,9 +595,9 @@ class StreamEngine:
             for wid in due:
                 state = self.windows[wid]
                 state.expired = True
-                self.execute_window(wid, now, late=False)
+                self.execute_window(wid, now, late=False,
+                                    trace_parent=span)
                 self.policy.on_expiry(state, self.io, now)
-        span.end()
 
     def _submit_round(self, items: List[BatchWorkItem], now: float,
                       expiry: bool = False, parent=None) -> None:
@@ -605,55 +618,81 @@ class StreamEngine:
         self.result_futures.update(futs)
 
     # ----------------------------------------------------------- execution
-    def execute_window(self, wid: WindowId, now: float, late: bool) -> Any:
+    def execute_window(self, wid: WindowId, now: float, late: bool,
+                       trace_parent=None) -> Any:
+        span = (self.tracer.child(trace_parent, "execute_window", late=late)
+                if trace_parent is not None
+                else self.tracer.root("execute_window", late=late))
+        with span:
+            return self._execute_window(wid, now, late, span)
+
+    def _execute_window(self, wid: WindowId, now: float, late: bool,
+                        span) -> Any:
         state = self.windows[wid]
+        t0_ns = _time.time_ns()
         t0 = _time.time()
         stall = 0.0
+        tracer = self.tracer
+        fold_cache = getattr(self.operator.fold, "_cache_size", None) \
+            if span.sampled else None
+        cache0 = fold_cache() if fold_cache else 0
 
         # lazy block iteration: consume m-blocks while staging p-blocks
         # (the shared snapshot helper keeps the double-fold hazard logic
         # in one place)
         m_snapshot, p_blocks = snapshot_block_partition(state)
+        # blocks by tier, for the execution record and the span: kept
+        # only while tracing is on
+        tiers = tier_counts(m_snapshot, p_blocks) if tracer.enabled \
+            else None
         stage_done = None
         stage_t0 = _time.time()
         staged_events = sum(b.fill for b in p_blocks)
         if p_blocks:
             if self.operator.blocking:
-                ev = self.io.request_stage(state, p_blocks, demand=True)
+                ev = self.io.request_stage(state, p_blocks, demand=True,
+                                           parent=span)
                 w0 = _time.time()
-                ev.wait(timeout=60)
+                with tracer.child(span, "execute_window.stage_wait"):
+                    ev.wait(timeout=60)
                 stall += _time.time() - w0
                 ev.check()      # a failed demand stage aborts the fold
             else:
                 stage_done = self.io.request_stage(state, p_blocks,
-                                                   demand=True)
+                                                   demand=True, parent=span)
 
         acc = self.operator.init_acc()
         # pass 1: blocks already on device (fetch_block_arrays prefers
         # device residency — per-block device_data or the pool arena —
         # and falls back to the accounted host read; None = purged)
-        for blk in m_snapshot:
-            data = self.io.fetch_block_arrays(blk)
-            if data is None:
-                continue                        # purged mid-execution
-            acc = self.operator.fold(acc, data, blk.fill)
+        with tracer.child(span, "execute_window.fold",
+                          blocks=len(m_snapshot)):
+            for blk in m_snapshot:
+                data = self.io.fetch_block_arrays(blk)
+                if data is None:
+                    continue                    # purged mid-execution
+                acc = self.operator.fold(acc, data, blk.fill)
         # pass 2: blocks arriving from the p-bucket (staging that could
         # not reserve budget leaves them host-side; same fetch logic)
         if stage_done is not None:
             w0 = _time.time()
-            stage_done.wait(timeout=60)
+            with tracer.child(span, "execute_window.stage_wait"):
+                stage_done.wait(timeout=60)
             stall += max(_time.time() - w0 - 0.0, 0.0)
             stage_done.check()  # surface a failed demand stage
-        for blk in p_blocks:
-            data = self.io.fetch_block_arrays(blk)
-            if data is None:
-                continue                        # purged mid-execution
-            acc = self.operator.fold(acc, data, blk.fill)
+        with tracer.child(span, "execute_window.fold",
+                          blocks=len(p_blocks)):
+            for blk in p_blocks:
+                data = self.io.fetch_block_arrays(blk)
+                if data is None:
+                    continue                    # purged mid-execution
+                acc = self.operator.fold(acc, data, blk.fill)
         if p_blocks and staged_events:
             self.prestage.cost.observe(_time.time() - stage_t0,
                                        staged_events)
 
-        result = self.operator.finalize(acc)
+        with tracer.child(span, "execute_window.finalize"):
+            result = self.operator.finalize(acc)
         state.result = result
         self.results[wid] = result
         state.last_executed_at = now
@@ -664,6 +703,14 @@ class StreamEngine:
             self.metrics.late_executions += 1
         else:
             self.metrics.live_executions += 1
+        if tiers is not None:
+            self.metrics.executions.append({
+                "window": wid.start, "late": late, "path": "single",
+                "t0": t0_ns, "t1": _time.time_ns(), "blocks": tiers})
+        if span.sampled:
+            span.set(events=state.total_events, **tiers)
+            if fold_cache:
+                span.set(recompiled=bool(fold_cache() > cache0))
         self._post_execute_destage(wid, state, now)
         return result
 
@@ -694,10 +741,10 @@ class StreamEngine:
             if self.batching_enabled:
                 self._poll_reexec_batched(now, parent=span)
             else:
-                self._poll_reexec_reference(now)
+                self._poll_reexec_reference(now, parent=span)
             self._poll_tail(now, parent=span)
 
-    def _poll_reexec_reference(self, now: float) -> None:
+    def _poll_reexec_reference(self, now: float, parent=NULL_SPAN) -> None:
         """Per-window reference path: one execution per due plan time."""
         for wid, plan in list(self.reexec_plans.items()):
             state = self.windows.get(wid)
@@ -706,7 +753,8 @@ class StreamEngine:
                 continue
             while plan.next_idx < len(plan.times) and \
                     plan.times[plan.next_idx] <= now:
-                self.execute_window(wid, now, late=True)
+                self.execute_window(wid, now, late=True,
+                                    trace_parent=parent)
                 plan.next_idx += 1
                 if self.prestage_enabled and plan.next_idx < len(plan.times):
                     self.prestage.plan(wid, state,
@@ -862,8 +910,8 @@ class StreamEngine:
             raise ValueError(f"unknown export format: {export!r}")
         eng = self.metrics.scalars()
         eng["mean_batch_occupancy"] = self.metrics.mean_batch_occupancy
-        eng["device_seconds_per_execution"] = \
-            self.metrics.device_seconds_per_execution
+        eng["dispatch_seconds_per_execution"] = \
+            self.metrics.dispatch_seconds_per_execution
         snap: Dict[str, Any] = {
             "engine": eng,
             "io": self.io.stats.copy(),

@@ -344,7 +344,8 @@ class MultiTenantEngine:
         # every tenant, the shared executor, store, arena, and pipeline
         self.registry = MetricsRegistry()
         self.tracer = Tracer(sample_rate=self.aion.trace_sample_rate,
-                             capacity=self.aion.trace_ring_max)
+                             capacity=self.aion.trace_ring_max,
+                             profile=self.aion.profiler_annotations)
         self.budget = MemoryBudget(device_budget_bytes)
         self.store = None
         if spill_dir is not None:
@@ -354,7 +355,7 @@ class MultiTenantEngine:
                 segment_bytes=self.aion.store_segment_bytes,
                 sim_spb=simulated_seconds_per_byte,
                 readahead_bytes=self.aion.store_readahead_bytes,
-                registry=self.registry)
+                registry=self.registry, tracer=self.tracer)
         self.executor = TransferExecutor(sequential_io=sequential_io,
                                          registry=self.registry)
         # one shared arena, sized for the width most tenant device
@@ -371,7 +372,7 @@ class MultiTenantEngine:
                 pool = DeviceBlockPool(
                     self.aion.pool_slots, self.aion.block_size, width,
                     max_arena_bytes=device_budget_bytes // 2,
-                    registry=self.registry)
+                    registry=self.registry, tracer=self.tracer)
                 if pool.pool_slots > 0 \
                         and self.budget.try_reserve(pool.arena_bytes):
                     self.pool = pool

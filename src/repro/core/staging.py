@@ -522,22 +522,20 @@ class IOScheduler:
         ``span``: the task's trace span (created by the request_*
         methods BEFORE the closure so retries inside it can record
         events). The wrapper marks queue->dispatch, observes the task
-        latency histogram by priority class, and ends the span when the
-        task finishes on the executor thread."""
+        latency histogram by priority class, and enters the span on the
+        executor thread (its profiler annotation covers the run, not the
+        wait in the queue) and ends it when the task finishes."""
         hist = self._task_hist.labels(self.tenant,
                                       PRIO_NAMES.get(priority, str(priority)))
 
         def run():
-            span.event("dispatch")
-            t0 = time.time()
-            try:
-                fn()
-            except BaseException as exc:
-                span.set(error=type(exc).__name__)
-                raise
-            finally:
-                hist.observe(time.time() - t0)
-                span.end()
+            with span:
+                span.event("dispatch")
+                t0 = time.time()
+                try:
+                    fn()
+                finally:
+                    hist.observe(time.time() - t0)
         return self.executor.submit(priority, run, tenant=self.tenant,
                                     on_error=self._record_error)
 

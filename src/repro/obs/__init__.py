@@ -2,11 +2,11 @@
 exporters shared by the engine, I/O scheduler, stores, pool, and pipeline."""
 from .registry import (BoundedSeries, Counter, Gauge, Histogram,
                        MetricsRegistry, StatsMap)
-from .trace import NULL_SPAN, NullSpan, Span, Tracer
-from .export import profiler_annotation, to_json, to_prometheus
+from .trace import NULL_SPAN, NullSpan, ProfiledSpan, Span, Tracer
+from .export import to_json, to_prometheus
 
 __all__ = [
     "BoundedSeries", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "StatsMap", "NULL_SPAN", "NullSpan", "Span", "Tracer",
-    "profiler_annotation", "to_json", "to_prometheus",
+    "StatsMap", "NULL_SPAN", "NullSpan", "ProfiledSpan", "Span", "Tracer",
+    "to_json", "to_prometheus",
 ]

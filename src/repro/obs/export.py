@@ -1,16 +1,11 @@
-"""Registry exporters: Prometheus text exposition, JSON snapshot, and an
-optional ``jax.profiler`` trace-annotation hook for fold launches."""
+"""Registry exporters: Prometheus text exposition and JSON snapshot."""
 from __future__ import annotations
 
-import contextlib
 import json
-from typing import Dict
-
-import jax
 
 from .registry import Histogram, MetricsRegistry, _HistogramChild
 
-__all__ = ["to_prometheus", "to_json", "profiler_annotation"]
+__all__ = ["to_prometheus", "to_json"]
 
 
 def _fmt_labels(labelnames, labelvalues) -> str:
@@ -60,14 +55,3 @@ def to_json(registry: MetricsRegistry, indent=None) -> str:
     return json.dumps(registry.snapshot(), indent=indent, sort_keys=True,
                       default=str)
 
-
-@contextlib.contextmanager
-def profiler_annotation(name: str, enabled: bool = True):
-    """Wrap a region in ``jax.profiler.TraceAnnotation``; a no-op when
-    disabled, so callers can wrap fold launches unconditionally and gate
-    with a config knob."""
-    if not enabled:
-        yield
-        return
-    with jax.profiler.TraceAnnotation(name):
-        yield

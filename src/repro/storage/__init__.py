@@ -14,15 +14,16 @@ from repro.storage.npzstore import NpzBlockStore
 def make_store(backend: str, directory, *, segment_bytes: int = 1 << 20,
                sim_spb: float = 0.0,
                readahead_bytes: int = 16 << 20,
-               registry=None) -> BlockStore:
+               registry=None, tracer=None) -> BlockStore:
     """Build a store by config name (``AionConfig.store_backend``)."""
     if backend == "log":
         return LogBlockStore(directory, segment_bytes=segment_bytes,
                              sim_spb=sim_spb,
                              readahead_bytes=readahead_bytes,
-                             registry=registry)
+                             registry=registry, tracer=tracer)
     if backend == "npz":
-        return NpzBlockStore(directory, sim_spb=sim_spb, registry=registry)
+        return NpzBlockStore(directory, sim_spb=sim_spb, registry=registry,
+                             tracer=tracer)
     raise ValueError(f"unknown store backend: {backend!r}")
 
 

@@ -29,6 +29,7 @@ to *storage*, never to files:
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
@@ -118,9 +119,11 @@ class BlockStore:
     #: (the legacy npz backend only flips the ``persisted`` flag).
     durable_writes = False
 
-    def __init__(self, sim_spb: float = 0.0, registry=None):
-        from repro.obs import MetricsRegistry, StatsMap
+    def __init__(self, sim_spb: float = 0.0, registry=None, tracer=None):
+        from repro.obs import MetricsRegistry, StatsMap, Tracer
         self.simcost = SimulatedCost(sim_spb)
+        # ``store.read`` spans go to the engine's tracer (off by default)
+        self.tracer = tracer if tracer is not None else Tracer()
         # registry-backed counters behind the legacy dict API; backends
         # extend the set via ``self.stats.update({...})`` (auto-registers)
         if registry is None:
@@ -133,7 +136,22 @@ class BlockStore:
             "logical_bytes_written", "batched_reads",
             "readahead_hits", "readahead_misses",
             "readahead_bytes", "compactions",
+            # host seconds inside reads from the store's files, summed
+            # over the threads that read
+            "read_seconds",
         ])
+
+    @contextlib.contextmanager
+    def _reading(self):
+        """One read from the store's files: a ``store.read`` span under
+        the reading thread's own (the caller sets its ``blocks`` and
+        ``bytes`` when sampled), timed into ``stats['read_seconds']``."""
+        t0 = time.perf_counter()
+        try:
+            with self.tracer.inner("store.read") as span:
+                yield span
+        finally:
+            self.stats.inc("read_seconds", time.perf_counter() - t0)
 
     # ------------------------------------------------------------- writes
     def put(self, window_key: Optional[WindowKey], block_id: int,

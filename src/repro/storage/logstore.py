@@ -146,8 +146,8 @@ class LogBlockStore(BlockStore):
 
     def __init__(self, directory: Path, *, segment_bytes: int = 1 << 20,
                  sim_spb: float = 0.0, readahead_bytes: int = 16 << 20,
-                 fsync: bool = True, registry=None):
-        super().__init__(sim_spb=sim_spb, registry=registry)
+                 fsync: bool = True, registry=None, tracer=None):
+        super().__init__(sim_spb=sim_spb, registry=registry, tracer=tracer)
         self.directory = Path(directory)
         self.directory.mkdir(parents=True, exist_ok=True)
         self.segment_bytes = max(int(segment_bytes), 4096)
@@ -510,26 +510,34 @@ class LogBlockStore(BlockStore):
                       ) -> Dict[BlockKey, dict]:
         """Batched record reads, one sequential sweep per segment."""
         out: Dict[BlockKey, dict] = {}
+        if not locs:
+            return out
         by_seg: Dict[int, List[Tuple[BlockKey, _Entry]]] = {}
         for key, sid, e in locs:
             by_seg.setdefault(sid, []).append((key, e))
-        for sid, items in by_seg.items():
-            seg = self._segs.get(sid)
-            if seg is None:
-                continue
-            if sid == self._active_sid:
-                self._active_f.flush()     # make buffered tail readable
-            with open(seg.path, "rb") as f:
-                for key, e in sorted(items, key=lambda it: it[1].offset):
-                    f.seek(e.offset)
-                    rec = f.read(e.rec_len)
-                    if len(rec) < e.rec_len:
-                        continue
-                    payload = self._record_payload(rec)
-                    if payload is None:
-                        continue
-                    out[key] = self._decode(e, payload)
-                    self.stats["bytes_read"] += e.rec_len
+        nbytes = 0
+        with self._reading() as span:
+            for sid, items in by_seg.items():
+                seg = self._segs.get(sid)
+                if seg is None:
+                    continue
+                if sid == self._active_sid:
+                    self._active_f.flush()  # make buffered tail readable
+                with open(seg.path, "rb") as f:
+                    for key, e in sorted(items,
+                                         key=lambda it: it[1].offset):
+                        f.seek(e.offset)
+                        rec = f.read(e.rec_len)
+                        if len(rec) < e.rec_len:
+                            continue
+                        payload = self._record_payload(rec)
+                        if payload is None:
+                            continue
+                        out[key] = self._decode(e, payload)
+                        nbytes += e.rec_len
+            if span.sampled:
+                span.set(blocks=len(out), bytes=nbytes)
+        self.stats.inc("bytes_read", nbytes)
         return out
 
     def get(self, window_key, block_id):
@@ -666,19 +674,22 @@ class LogBlockStore(BlockStore):
             else:
                 if sid == self._active_sid:
                     self._active_f.flush()
-                with open(seg.path, "rb") as f:
-                    f.seek(lo)
-                    blob = f.read(span)
+                got = {}
+                with self._reading() as read_span:
+                    with open(seg.path, "rb") as f:
+                        f.seek(lo)
+                        blob = f.read(span)
+                    for key, e in want:
+                        rec = blob[e.offset - lo:e.offset - lo + e.rec_len]
+                        if len(rec) < e.rec_len:
+                            continue
+                        payload = self._record_payload(rec)
+                        if payload is not None:
+                            got[key] = self._decode(e, payload)
+                    if read_span.sampled:
+                        read_span.set(blocks=len(got), bytes=len(blob))
                 self.stats["bytes_read"] += len(blob)
                 self.stats["sweep_bytes_read"] += len(blob)
-                got = {}
-                for key, e in want:
-                    rec = blob[e.offset - lo:e.offset - lo + e.rec_len]
-                    if len(rec) < e.rec_len:
-                        continue
-                    payload = self._record_payload(rec)
-                    if payload is not None:
-                        got[key] = self._decode(e, payload)
             self.stats["segment_sweeps"] += 1
             for key, e in want:
                 arrays = got.get(key)

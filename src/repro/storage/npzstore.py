@@ -30,8 +30,8 @@ class NpzBlockStore(BlockStore):
     durable_writes = False      # legacy late writes only flip `persisted`
 
     def __init__(self, directory: Path, sim_spb: float = 0.0,
-                 registry=None):
-        super().__init__(sim_spb=sim_spb, registry=registry)
+                 registry=None, tracer=None):
+        super().__init__(sim_spb=sim_spb, registry=registry, tracer=tracer)
         self.directory = Path(directory)
         # engine main thread (purge tombstones) and the I/O executor
         # (spill/stage) both call in
@@ -112,8 +112,10 @@ class NpzBlockStore(BlockStore):
             path, _, _, disk = self._index[key]
             if not path.exists():
                 return None
-            with np.load(path) as z:
+            with self._reading() as span, np.load(path) as z:
                 out = {k: z[k] for k in FIELDS}
+                if span.sampled:
+                    span.set(blocks=1, bytes=disk)
             self.stats["gets"] += 1
             self.stats["bytes_read"] += disk
             return out

@@ -92,7 +92,7 @@ def test_batched_matches_reference_late_heavy(op_name, pooled):
     assert m_b.batch_executions >= 1
     assert m_b.mean_batch_occupancy > 1.0
     assert m_b.batched_windows >= N_WINDOWS
-    assert m_b.batch_device_seconds > 0.0
+    assert m_b.batch_dispatch_seconds > 0.0
     if pooled:
         # zero-copy block-table rows carried the batch
         assert m_b.pooled_rows > 0

@@ -384,11 +384,239 @@ def test_multitenant_observability_covers_everything(tmp_path):
     mt.close()
 
 
-def test_tracing_overhead_disabled_is_free(tmp_path):
-    """With sampling off the hot path must allocate nothing: every span
-    handed out is THE NullSpan singleton."""
+def _spill_window(eng):
+    """Push the first window's blocks through host to the log store, so
+    a later stage or execution reads them back."""
+    state = next(iter(eng.windows.values()))
+    for blk in list(state.blocks):
+        eng.io.destage_block_sync(blk)
+    eng.io.spill_blocks_sync(list(state.blocks))
+    return state
+
+
+def _late_spilled_run(eng):
+    """Live executions, a spilled window read back by a demand stage,
+    then late events executed by a poll far ahead."""
+    eng.ingest(_batch(600, hi=40.0), now=1.0)
+    state = _spill_window(eng)
+    assert eng.io.request_stage(state, demand=True).wait_checked(30.0)
+    eng.advance_watermark(50.0, now=2.0)
+    eng.ingest(_batch(64, seed=3, hi=10.0), now=3.0)
+    eng.poll(200.0)
+    assert eng.io.drain(timeout=30)
+
+
+def test_tracing_overhead_disabled_is_free(tmp_path, monkeypatch):
+    """With sampling and the profiler sink off the hot path must
+    allocate nothing: every span handed out is THE NullSpan singleton,
+    and a run through ingest, store reads, arena fills and late
+    executions builds no span and opens no profiler annotation."""
+    from repro.obs import trace as trace_mod
+    made = []
+
+    class CountingSpan(trace_mod.Span):
+        __slots__ = ()
+
+        def __init__(self, *a, **kw):
+            made.append("span")
+            super().__init__(*a, **kw)
+
+    class CountingProfiledSpan(trace_mod.ProfiledSpan):
+        __slots__ = ()
+
+        def __init__(self, *a, **kw):
+            made.append("profiled")
+            super().__init__(*a, **kw)
+
+    def counting_annotation(name):
+        made.append(name)
+        return trace_mod.TraceAnnotation(name)
+    monkeypatch.setattr(trace_mod, "Span", CountingSpan)
+    monkeypatch.setattr(trace_mod, "ProfiledSpan", CountingProfiledSpan)
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", counting_annotation)
     eng = _engine(tmp_path)
     assert eng.tracer.root("a") is NULL_SPAN
     assert eng.tracer.child(NULL_SPAN, "b") is NULL_SPAN
     assert eng.tracer.child(None, "c") is NULL_SPAN
+    _late_spilled_run(eng)
+    assert eng.metrics.late_executions > 0
+    assert eng.store.stats["read_seconds"] > 0
+    assert len(eng.metrics.executions) == 0     # records only when on
+    eng.close()
+    assert made == []
+
+
+# ============================================ profiler sink (device clock)
+def _profiler_session():
+    import jax
+    from jax._src.lib import _profiler
+    jax.devices()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1           # user annotations only
+    return _profiler.ProfilerSession(opts)
+
+
+def _aion_events(session):
+    """``(line, name, start_ns, duration_ns)`` of every ``aion.*`` host
+    event of the stopped session; ``line`` is the index of the host
+    plane's line (one line per thread)."""
+    from jax.profiler import ProfileData
+    pd = ProfileData.from_serialized_xspace(session.stop())
+    out = []
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("aion."):
+                    out.append((i, ev.name, int(ev.start_ns),
+                                int(ev.duration_ns)))
+    return out
+
+
+def test_profiler_sink_puts_sampled_spans_on_the_trace_clock():
+    """Two sampled spans land in the profiler trace as ``aion.<name>``;
+    the gap between their starts and each one's duration agree within
+    1 ms between the JSON-lines export and the xplane."""
+    import time
+    tr = Tracer(sample_rate=1.0, profile=True)
+    session = _profiler_session()
+    with tr.root("first"):
+        time.sleep(0.02)
+    time.sleep(0.01)
+    with tr.root("second"):
+        time.sleep(0.03)
+    events = {name: (start, dur)
+              for _, name, start, dur in _aion_events(session)}
+    recs = {r["name"]: r for r in
+            (json.loads(l) for l in tr.export_jsonl().splitlines())}
+    assert set(events) == {"aion.first", "aion.second"}
+    gap_x = events["aion.second"][0] - events["aion.first"][0]
+    gap_j = recs["second"]["t0"] - recs["first"]["t0"]
+    assert gap_j > 0.03e9
+    assert abs(gap_x - gap_j) < 1_000_000
+    for name in ("first", "second"):
+        assert abs(events["aion." + name][1] - recs[name]["dur"]) \
+            < 1_000_000
+
+
+def test_profiler_sink_io_task_span_on_the_executor_thread(tmp_path):
+    """Under the sink with sampling off, spans are profiler annotations
+    only (the ring stays empty); an I/O task span created at submit
+    time on the ingest thread is annotated where the task runs, on the
+    executor thread's line."""
+    eng = _engine(tmp_path, profiler_annotations=True)
+    session = _profiler_session()
+    eng.ingest(_batch(600, hi=40.0), now=1.0)
+    eng.advance_watermark(50.0, now=2.0)
+    eng.ingest(_batch(64, seed=3, hi=10.0), now=3.0)   # late: io.late_write
+    eng.poll(200.0)
+    assert eng.io.drain(timeout=30)
+    events = _aion_events(session)
+    eng.close()
+    assert eng.tracer.records() == []
+    lines = {}
+    for line, name, _, _ in events:
+        lines.setdefault(name, set()).add(line)
+    for name in ("aion.ingest", "aion.watermark_advance", "aion.poll",
+                 "aion.execute_window", "aion.io.late_write"):
+        assert name in lines, name
+    assert lines["aion.io.late_write"].isdisjoint(lines["aion.ingest"])
+
+
+@pytest.mark.parametrize("backend", ["log", "npz"])
+def test_store_reads_and_fills_join_the_callers_trace(tmp_path, backend):
+    """At sample rate 1.0 store reads and arena fills are children of
+    the span their thread runs under, never traces of their own: a late
+    execution's reads of its spilled blocks share its trace id."""
+    eng = _engine(tmp_path, trace_sample_rate=1.0, store_backend=backend)
+    eng.ingest(_batch(200, hi=9.0), now=1.0)      # one window, [0, 10)
+    eng.advance_watermark(20.0, now=2.0)
+    _spill_window(eng)
+    eng.tracer.clear()
+    eng.ingest(_batch(32, seed=5, hi=9.0), now=3.0)
+    eng.poll(200.0)
+    assert eng.io.drain(timeout=30)
+    recs = eng.tracer.records()
+    eng.close()
+    assert eng.metrics.late_executions == 1
+    by_id = {r["span"]: r for r in recs}
+    inner = [r for r in recs if r["name"] in ("store.read", "pool.fill")]
+    assert {r["name"] for r in inner} >= {"store.read"}
+    assert all(r["parent"] in by_id for r in inner)
+    execs = [r for r in recs if r["name"] == "execute_window"]
+    assert len(execs) == 1 and execs[0]["attrs"]["late"] is True
+    trace = execs[0]["trace"]
+    reads = [r for r in inner if r["name"] == "store.read"]
+    assert reads and all(r["trace"] == trace for r in reads)
+    assert execs[0]["attrs"]["storage"] > 0
+    # roots are the engine's entry points, one trace each
+    roots = {r["name"] for r in recs if r["parent"] is None}
+    assert roots <= {"ingest", "poll", "watermark_advance"}
+
+
+def test_inner_span_without_an_entered_span_is_never_sampled():
+    tr = Tracer(sample_rate=1.0)
+    assert tr.inner("store.read") is NULL_SPAN
+    with tr.root("poll") as outer:
+        with tr.inner("store.read") as read:
+            assert read.trace_id == outer.trace_id
+            assert tr.inner("pool.fill").parent_id == read.span_id
+        assert tr.inner("pool.fill").parent_id == outer.span_id
+    assert tr.inner("pool.fill") is NULL_SPAN
+    profiled = Tracer(profile=True)
+    assert not profiled.inner("store.read").sampled
+    assert Tracer().inner("store.read") is NULL_SPAN
+
+
+# ==================================== store, arena and execution counters
+def test_store_read_and_pool_fill_seconds(tmp_path):
+    """Reading a spilled block back is timed into the store's
+    ``read_seconds``, its arena fill into the pool's ``fill_seconds``."""
+    eng = _engine(tmp_path)
+    assert eng.pool is not None
+    eng.ingest(_batch(256), now=1.0)
+    state = _spill_window(eng)
+    assert eng.store.stats["read_seconds"] == 0
+    fills0 = eng.pool.stats["writes"]
+    assert eng.io.request_stage(state, demand=True).wait_checked(30.0)
+    assert eng.io.drain(timeout=30)
+    assert eng.store.stats["bytes_read"] > 0
+    assert eng.store.stats["read_seconds"] > 0
+    assert eng.pool.stats["writes"] > fills0
+    assert eng.pool.stats["fill_seconds"] > 0
+    assert "read_seconds" in eng.observability()["store"]
+    eng.close()
+
+
+def test_late_execution_leaves_one_record_and_the_series_stays_capped(
+        tmp_path):
+    """With tracing on, a single-window late execution appends one
+    record with ``late``, its path and its blocks by tier; the series
+    keeps its cap."""
+    eng = _engine(tmp_path, metrics_series_max=8,
+                  profiler_annotations=True)
+    eng.ingest(_batch(200, hi=9.0), now=1.0)      # one window, [0, 10)
+    eng.advance_watermark(20.0, now=2.0)
+    live = list(eng.metrics.executions)
+    assert len(live) == 1 and live[0]["late"] is False
+    state = _spill_window(eng)
+    eng.ingest(_batch(32, seed=5, hi=9.0), now=3.0)
+    n0 = len(eng.metrics.executions)
+    eng.poll(200.0)
+    recs = list(eng.metrics.executions)[n0:]
+    assert len(recs) == 1
+    rec = recs[0]
+    assert rec["late"] is True and rec["path"] == "single"
+    assert rec["window"] == 0.0
+    assert rec["t1"] >= rec["t0"] > 0
+    assert set(rec["blocks"]) == {"device", "host", "storage"}
+    assert sum(rec["blocks"].values()) == len(state.blocks)
+    assert rec["blocks"]["storage"] > 0
+    wid = next(iter(eng.windows))
+    for _ in range(40):
+        eng.execute_window(wid, 300.0, late=True)
+    assert len(eng.metrics.executions) <= 8
+    assert eng.metrics.executions[-1]["late"] is True
     eng.close()
