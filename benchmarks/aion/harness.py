@@ -3,10 +3,20 @@
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file of its own, found by the name in ``BENCHMARK.json``:
 
-    configs/<config>.json     deployment: sizes, budgets, guarantees
+    configs/<config>.json     deployment: sizes, budgets, guarantees,
+                              windows (``window_s``, optional
+                              ``slide_s``), optional ``fold_kernels``
     reference/<operator>.py   plain float64 reference of the operator
-    traffic/<traffic>.json    lateness model, keys, loop and rate
+    events/<operator>.py      ``draw(rng, n, config)``: keys and values
+    traffic/<traffic>.json    lateness model, loop and rate
     metrics/<metric>.py       ``read(rec)`` -> number or None
+
+A configuration's windows hop: ``window_s`` long, one starting every
+``slide_s`` (``Windows``); without ``slide_s`` they are tumbling. Its
+``fold_kernels`` are the op-name prefixes whose device time the trace
+reduction sums (``FOLD_KERNELS`` without it). ``counters`` carries
+every numeric counter of the engine, its pool and its store, so a
+metric file reads a new mechanism's counter by name.
 
 The window drives ``StreamEngine.ingest(batch, now)``, then
 ``advance_watermark(now, now)``, then ``poll(now)`` once per step, where
@@ -24,7 +34,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -37,6 +47,52 @@ GIB = float(1 << 30)
 #: segment_aggregate`` that made it (``segment_aggregate_batched``,
 #: ``segment_aggregate_block_table_splitk``, ...)
 FOLD_KERNELS = ("segment_aggregate",)
+
+
+def fold_kernels(config: dict) -> Tuple[str, ...]:
+    """The op-name prefixes of the configuration's kernels."""
+    return tuple(config.get("fold_kernels", FOLD_KERNELS))
+
+
+@dataclass(frozen=True)
+class Windows:
+    """Hop windows: each ``size`` seconds long, one starting at every
+    multiple of ``slide``; tumbling where ``slide == size``. Written
+    from those semantics, apart from the program's assigners."""
+    size: float
+    slide: float
+
+    def __post_init__(self):
+        if not 0 < self.slide <= self.size:
+            raise ValueError(f"a slide of {self.slide} s for windows of "
+                             f"{self.size} s: it must be in (0, size]")
+
+    @classmethod
+    def of(cls, config: dict) -> "Windows":
+        size = float(config["window_s"])
+        return cls(size, float(config.get("slide_s", size)))
+
+    @property
+    def tumbling(self) -> bool:
+        return self.slide == self.size
+
+    def assign(self, ts: np.ndarray) -> List[Tuple[float, np.ndarray]]:
+        """``[(start, mask)]`` in order of start: an event at ``ts`` is in
+        every window whose start is a multiple of ``slide`` with
+        ``start <= ts < start + size``. The window of the slide an event
+        falls in is ``floor(ts / slide) * slide``, and the k-th before it
+        starts ``k * slide`` earlier."""
+        ts = np.asarray(ts)
+        base = np.floor(ts / self.slide) * self.slide
+        out: Dict[float, np.ndarray] = {}
+        for k in range(int(np.ceil(self.size / self.slide))):
+            starts = base - k * self.slide
+            inside = ts < starts + self.size
+            for s in np.unique(starts[inside]):
+                mask = inside & (starts == s)
+                s = float(s)
+                out[s] = out[s] | mask if s in out else mask
+        return sorted(out.items(), key=lambda kv: kv[0])
 
 
 def load_module(path: Path):
@@ -60,12 +116,13 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
     reference: object
+    events: object
 
 
 def load_cell(name: str, bench: Optional[dict] = None,
               root: Path = HERE) -> Cell:
     """The cell named ``name`` with its configuration, traffic, metrics
-    and reference, all found by name under ``root``."""
+    reference and events, all found by name under ``root``."""
     if bench is None:
         bench = json.loads((CHECKOUT / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -82,8 +139,10 @@ def load_cell(name: str, bench: Optional[dict] = None,
     per_layer = [m for m in bench["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in reported)]
-    ref = load_module(root / "reference" / f"{config['operator']}.py")
-    return Cell(name, config, traffic, e2e, per_layer, ref)
+    op = config["operator"]
+    return Cell(name, config, traffic, e2e, per_layer,
+                load_module(root / "reference" / f"{op}.py"),
+                load_module(root / "events" / f"{op}.py"))
 
 
 class CompileLog:
@@ -179,7 +238,7 @@ class Producer:
 
 def make_engine(config: dict, store_dir: Path, annotate: bool):
     from repro.configs.base import AionConfig
-    from repro.core import StreamEngine, TumblingWindows
+    from repro.core import SlidingWindows, StreamEngine, TumblingWindows
     from repro.core.operators import make_operator
     g = config["guarantees"]
     aion = AionConfig(block_size=config["block_size"],
@@ -192,18 +251,29 @@ def make_engine(config: dict, store_dir: Path, annotate: bool):
                       profiler_annotations=annotate)
     op = make_operator(config["operator"], aion.block_size,
                        config["value_width"], **config["operator_args"])
+    w = Windows.of(config)
     return StreamEngine(
-        assigner=TumblingWindows(float(config["window_s"])),
+        assigner=(TumblingWindows(w.size) if w.tumbling
+                  else SlidingWindows(w.size, w.slide)),
         operator=op, aion=aion, value_width=config["value_width"],
         device_budget_bytes=config["device_budget_bytes"],
         host_budget_bytes=config["host_budget_bytes"],
         spill_dir=store_dir)
 
 
+def _numeric(prefix: str, items) -> dict:
+    return {f"{prefix}.{k}": v for k, v in items
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+
+
 def counters(eng) -> dict:
+    """The engine's counters now: the named ones the metric files of
+    the first cells read, then every numeric scalar of the engine's
+    metrics, its pool's and its store's stats as ``metrics.<name>``,
+    ``pool.<name>`` and ``store.<name>``."""
     m = eng.metrics
     store = eng.store.stats if eng.store is not None else {}
-    return {
+    out = {
         "ingested": m.ingested, "ingested_late": m.ingested_late,
         "exec_seconds": m.exec_seconds,
         "live_executions": m.live_executions,
@@ -217,6 +287,11 @@ def counters(eng) -> dict:
         "store_bytes_read": store.get("bytes_read", 0),
         "store_bytes_written": store.get("bytes_written", 0),
     }
+    out.update(_numeric("metrics", m.scalars().items()))
+    if eng.pool is not None:
+        out.update(_numeric("pool", eng.pool.stats.items()))
+    out.update(_numeric("store", store.items()))
+    return out
 
 
 def delta(a: dict, b: dict) -> dict:
@@ -234,9 +309,9 @@ def _annotation(on: bool, name: str):
 class Driver:
     """Drives one engine through the stream and records what it sees."""
 
-    def __init__(self, eng, window_s: float, ref_cols: int):
+    def __init__(self, eng, windows: Windows, ref_cols: int):
         self.eng = eng
-        self.window_s = window_s
+        self.windows = windows
         self.ref_cols = ref_cols
         self.steps: List[Step] = []
         self.seen: dict = {}
@@ -259,11 +334,10 @@ class Driver:
                   window=window, due=due)
         if window:
             # late as the engine sees it: a window that closed already
-            wstart = np.floor(ts / self.window_s) * self.window_s
-            late = wstart + self.window_s <= self.wm
-            if late.any():
-                s, n = np.unique(wstart[late], return_counts=True)
-                st.late_by_window = dict(zip(s.tolist(), n.tolist()))
+            size = self.windows.size
+            st.late_by_window = {
+                s: int(mask.sum()) for s, mask in self.windows.assign(ts)
+                if s + size <= self.wm}
         eng = self.eng
         ann = self.annotate
         ex0 = eng.metrics.exec_seconds
@@ -303,7 +377,7 @@ class Driver:
         return st
 
 
-def check(steps: List[Step], ref, window_s: float, num_keys: int,
+def check(steps: List[Step], ref, windows: Windows, num_keys: int,
           watermark: float, control=None) -> dict:
     """Replay the stream through the reference and compare every
     emission of the window and of the flush after it.
@@ -325,10 +399,7 @@ def check(steps: List[Step], ref, window_s: float, num_keys: int,
     first_window = next((s.index for s in steps if s.window), None)
     for st in steps:
         if len(st.ts):
-            wstart = np.floor(st.ts / window_s) * window_s
-            for s in np.unique(wstart):
-                sel = wstart == s
-                s = float(s)
+            for s, sel in windows.assign(st.ts):
                 if s not in state:
                     state[s] = ref.Window(num_keys)
                     if control is not None:
@@ -363,7 +434,7 @@ def check(steps: List[Step], ref, window_s: float, num_keys: int,
     missing = 0
     if first_window is not None:
         for s, k in last_event.items():
-            if s + window_s <= watermark and k >= first_window \
+            if s + windows.size <= watermark and k >= first_window \
                     and last_emit.get(s, -1) < k:
                 missing += 1
     return {"numbers": numbers, "attempted": attempted + missing,

@@ -11,7 +11,7 @@ reference takes the program's place on the same answers, computed
 The limits in ``reference/<operator>.py`` lie between the program's
 readings (the run's ``checks``) and the controls'.
 
-    python3 benchmarks/aion/readings.py --workload stock.lnorm.max \\
+    python3 benchmarks/aion/readings.py --workload <workload> \\
         --seed 7 --seconds 51 --trace 0
 
 Prints the run's result line and, last on standard error before the
@@ -46,7 +46,7 @@ def control(cell: harness.Cell):
     def on_record(steps, rec, watermark):
         for name, kw in CONTROLS.items():
             got = harness.check(steps, cell.reference,
-                                cell.config["window_s"],
+                                harness.Windows.of(cell.config),
                                 cell.config["num_keys"], watermark,
                                 control=kw)
             print(f"control {name}: " + json.dumps(got["numbers"]),

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Aion's on-chip benchmark: one run of one cell.
 
-    python3 benchmarks/aion/run.py --workload stock.lnorm.max \\
+    python3 benchmarks/aion/run.py --workload <workload> \\
         --seed 7 --seconds 51 --trace 0
 
 A cell is one entry of ``workloads`` in ``BENCHMARK.json``: a deployment
@@ -162,15 +162,15 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, *,
         eng.close()
         raise RuntimeError(f"arena of {arena} B, the configuration states "
                            f"{cfg['arena_bytes']} B")
-    window_s = float(cfg["window_s"])
+    windows = harness.Windows.of(cfg)
     step_s = float(traffic["step_s"])
     per_step = int(round(cfg["event_rate"] * step_s))
     setup_events = (cfg["history_events"] if traffic["history"]
-                    else int(traffic["warmup_windows"] * window_s
+                    else int(traffic["warmup_windows"] * windows.size
                              * cfg["event_rate"]))
-    producer = harness.Producer(Generator(cfg, traffic, seed), per_step,
-                                step_s)
-    drv = harness.Driver(eng, window_s, ref.COLUMNS)
+    producer = harness.Producer(Generator(cfg, traffic, seed, cell.events),
+                                per_step, step_s)
+    drv = harness.Driver(eng, windows, ref.COLUMNS)
     try:
         # ---- set-up: the history, through the window's own calls
         for _ in range(setup_events // per_step):
@@ -260,7 +260,7 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, *,
              max(lag), lag[-1]] if lag else None)
     if traced is not None:
         rec["trace"] = trace_reduce.reduce(traced["data"],
-                                           harness.FOLD_KERNELS)
+                                           harness.fold_kernels(cfg))
         if rec["trace"] is not None:
             rec["trace"]["counters"] = traced["counters"]
         del traced
@@ -271,7 +271,7 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, *,
         on_record(steps, rec, watermark)
 
     # ---- the check, once the engine's state is freed
-    verdict = harness.check(steps, ref, window_s, cfg["num_keys"],
+    verdict = harness.check(steps, ref, windows, cfg["num_keys"],
                             watermark)
     log("answers compared", f"{verdict['attempted']} "
         f"({verdict['missing']} due and never given)")

@@ -9,7 +9,8 @@ annotations on the host. Within it:
   over the devices that ran any;
 - kernel time: the summed device durations of the operations whose kind
   (a TPU trace names each by its HLO text, ``%<kind>.<n> = ...``) starts
-  with one of the given prefixes;
+  with one of the given prefixes (``kernel_s``), and by prefix
+  (``kernel_s_by``);
 - idle gaps: each gap in the busy union, charged to the innermost host
   annotation (``bench.*`` or ``aion.*``) covering its midpoint.
 
@@ -110,6 +111,7 @@ def reduce(trace, kernel_prefixes: Iterable[str],
 
     busy_ns = []
     kernel_ns = 0
+    by_prefix = dict.fromkeys(kernels, 0)
     by_op: collections.Counter = collections.Counter()
     gaps: List[Tuple[int, int]] = []
     for ops in devices:
@@ -126,6 +128,9 @@ def reduce(trace, kernel_prefixes: Iterable[str],
             by_op[kind] += t
             if kind.startswith(kernels):
                 kernel_ns += t
+                for p in kernels:
+                    if kind.startswith(p):
+                        by_prefix[p] += t
         merged = _union(spans)
         busy_ns.append(sum(e - s for s, e in merged))
         edges = [lo] + [x for iv in merged for x in iv] + [hi]
@@ -145,6 +150,7 @@ def reduce(trace, kernel_prefixes: Iterable[str],
         "window_s": (hi - lo) / 1e9,
         "busy_s": sum(busy_ns) / n_dev / 1e9,
         "kernel_s": kernel_ns / n_dev / 1e9,
+        "kernel_s_by": {p: t / n_dev / 1e9 for p, t in by_prefix.items()},
         "devices": n_dev,
         "device_ops": [[n, t / n_dev / 1e9]
                        for n, t in by_op.most_common(top)],
